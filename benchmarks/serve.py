@@ -112,8 +112,8 @@ def run_cells_sweep(smoke: bool, rate: float) -> dict:
     sharded over every visible device, assert record parity ≤ 1e-5, and
     report per-size throughput rows.
 
-    Single-device serving runs the interpret-mode Pallas group-occupancy
-    kernel, whose cost grows with C²; the sharded path reduces the
+    Single-device serving runs the Pallas group-occupancy kernel
+    (interpreted on the CPU), whose cost grows with C²; the sharded path reduces the
     cross-cell couplings with ``segment_sum`` + ``psum`` per shard, so
     past the crossover fleet size the mesh wins even when the forced
     host devices share one physical core — the speedup is algorithmic

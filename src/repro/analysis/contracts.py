@@ -45,6 +45,7 @@ from typing import Any, Callable, Iterable, Mapping, Sequence
 
 import jax
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 LARGE_CONST_BYTES = 64 * 1024
 
@@ -61,10 +62,10 @@ CALLBACK_PRIMS = frozenset({"io_callback", "pure_callback", "debug_callback"})
 
 BANNED_DTYPES = frozenset({"float64", "complex128"})
 
-# shard_map's check_rep=True rewrite renames psum to psum2 (and pmax /
-# pmin likewise) inside the body jaxpr; inventory them under the plain
-# name so a collective cannot hide behind the replication-checking path.
-_PRIM_ALIASES = {"psum2": "psum", "pmax2": "pmax", "pmin2": "pmin"}
+# shard_map's check_vma=True binds psum of a device-varying value as
+# psum_invariant inside the body jaxpr; inventory it under the plain name
+# so a collective cannot hide behind the varying-axes checking path.
+_PRIM_ALIASES = {"psum_invariant": "psum"}
 
 # Jaxpr pretty-prints embed object addresses (``<function on_window at
 # 0x7f..>``); strip them so equal programs hash equal across processes.
@@ -103,13 +104,13 @@ def _iter_sub_jaxprs(params: Mapping[str, Any]):
     for v in params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for u in vs:
-            if isinstance(u, jax.core.ClosedJaxpr):
+            if isinstance(u, ClosedJaxpr):
                 yield u.jaxpr, u.consts
-            elif isinstance(u, jax.core.Jaxpr):
+            elif isinstance(u, Jaxpr):
                 yield u, ()
 
 
-def walk_jaxpr(closed: jax.core.ClosedJaxpr):
+def walk_jaxpr(closed: ClosedJaxpr):
     """Yield ``(eqn, depth)`` for every equation, recursing into nested
     jaxprs, plus collect (aval) constants along the way.
 
@@ -124,7 +125,7 @@ def walk_jaxpr(closed: jax.core.ClosedJaxpr):
                 stack.append((sub, depth + 1))
 
 
-def _collect_consts(closed: jax.core.ClosedJaxpr):
+def _collect_consts(closed: ClosedJaxpr):
     """Every constant array baked into the program, at any nesting depth."""
     out = list(closed.consts)
     stack = [closed.jaxpr]
@@ -170,7 +171,7 @@ def _var_dtypes(jaxpr_vars: Iterable[Any], acc: set) -> None:
             acc.add(str(dt))
 
 
-def jaxpr_fingerprint(closed: jax.core.ClosedJaxpr) -> str:
+def jaxpr_fingerprint(closed: ClosedJaxpr) -> str:
     """Digest of the jaxpr text with object addresses stripped, so two
     traces of the same program hash identically."""
     text = _ADDR_RE.sub("0xX", str(closed))
@@ -183,7 +184,7 @@ def jaxpr_fingerprint(closed: jax.core.ClosedJaxpr) -> str:
 
 def extract_contract(
     name: str,
-    closed: jax.core.ClosedJaxpr,
+    closed: ClosedJaxpr,
     *,
     declared_donate: Sequence[int] = (),
     aliased_outputs: int = 0,

@@ -20,9 +20,8 @@ import os
 
 # Registry of the repo's known flags (documentation + lint cross-check).
 ORCH_KERNELS = "REPRO_ORCH_KERNELS"       # bool: fused Pallas orchestration
-PALLAS_INTERPRET = "REPRO_PALLAS_INTERPRET"  # bool: Pallas interpret mode
 PROFILE_DIR = "REPRO_PROFILE_DIR"         # path: jax.profiler trace output
-KNOWN_FLAGS = (ORCH_KERNELS, PALLAS_INTERPRET, PROFILE_DIR)
+KNOWN_FLAGS = (ORCH_KERNELS, PROFILE_DIR)
 
 
 def bool_flag(name: str, default: bool) -> bool:

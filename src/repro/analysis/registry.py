@@ -43,9 +43,8 @@ from repro.kernels.ops import flash_attention
 from repro.kernels.orchestration import (group_occupancy_pallas,
                                          queue_admit_pallas)
 from repro.policy.adapters import heuristic_greedy_policy, oracle_policy
-from repro.policy.api import refresh_params
 from repro.serve.engine import (ECON_COUNTERS, ECON_GAUGES, TEL_COUNTERS,
-                                TEL_GAUGES, ServeConfig, _tick_buckets,
+                                TEL_GAUGES, ServeConfig, first_epoch_args,
                                 make_serve_engine)
 from repro.serve.stream import poisson_request_stream
 from repro.specs.observation import make_spec, spec_dim
@@ -90,19 +89,8 @@ def _serve_build(cfg: ServeConfig, *, n_cells: int = 4, sharded: bool = False,
     engine = make_serve_engine(policy, cfg, live=emitter, mesh=mesh)
     stream = poisson_request_stream(k_stream, scenario, 400.0, rate=1.0,
                                     round_ms=cfg.round_ms, epoch_ms=200.0)
-    ticks_per_epoch = max(1, int(round(stream.epoch_ms / cfg.tick_ms)))
-    ids, now, live_ticks, _ = _tick_buckets(
-        stream, cfg.tick_ms, ticks_per_epoch, n_shards=engine.n_shards)
-    n_windows = int((int(live_ticks.sum()) - 1)
-                    * cfg.tick_ms // cfg.window_ms) + 1
-    state = engine.init(k_init, scenario, stream.n_requests, n_windows)
-    params = refresh_params(policy, policy.init(k_pol), scenario)
-    lo, hi = 0, ticks_per_epoch
-    args = (params, scenario, state, jnp.asarray(ids[lo:hi]),
-            jnp.asarray(now[lo:hi]), jnp.asarray(live_ticks[lo:hi]),
-            jnp.asarray(np.append(stream.t_ms, 0.0), jnp.float32),
-            jnp.asarray(np.append(stream.cell, 0), jnp.int32),
-            jnp.asarray(np.append(stream.slo_ms, 0.0), jnp.float32))
+    args = first_epoch_args(engine, policy, policy.init(k_pol), scenario,
+                            stream, k_init)
     return engine.run_epoch, args, {}
 
 

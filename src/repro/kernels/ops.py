@@ -1,8 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; a real
-TPU deployment flips ``repro.kernels.ops.INTERPRET = False`` (or passes
-interpret=False) and the same code lowers to Mosaic.
+``interpret=None`` picks the mode from the backend
+(:func:`repro.kernels.orchestration.interpret_mode`): Mosaic on a TPU,
+the Pallas interpreter elsewhere.
 """
 from __future__ import annotations
 
@@ -10,14 +10,10 @@ import functools
 
 import jax
 
-from repro.analysis import envflags
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.orchestration import interpret_mode
 from repro.kernels.ssd import ssd_pallas
 from repro.kernels.wkv6 import wkv6_pallas
-
-# strict flag: REPRO_PALLAS_INTERPRET=0 lowers to Mosaic, =1 (default
-# here: CPU-only container) interprets; anything else raises at import
-INTERPRET = envflags.bool_flag(envflags.PALLAS_INTERPRET, True)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "q_blk",
@@ -26,7 +22,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_blk: int = 128, kv_blk: int = 128,
                     scale: float | None = None, interpret: bool | None = None):
     """Fused attention. q: (B,S,H,D); k/v: (B,S,KV,D|Dv) → (B,S,H,Dv)."""
-    it = INTERPRET if interpret is None else interpret
+    it = interpret_mode() if interpret is None else interpret
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   q_blk=q_blk, kv_blk=kv_blk, scale=scale,
                                   interpret=it)
@@ -36,7 +32,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 def wkv6(r, k, v, lw, u, *, chunk: int = 64, tile: int = 16,
          interpret: bool | None = None):
     """Chunked RWKV6 WKV. r/k/v/lw: (B,S,H,N); u: (H,N)."""
-    it = INTERPRET if interpret is None else interpret
+    it = interpret_mode() if interpret is None else interpret
     return wkv6_pallas(r, k, v, lw, u, chunk=chunk, tile=tile, interpret=it)
 
 
@@ -44,5 +40,5 @@ def wkv6(r, k, v, lw, u, *, chunk: int = 64, tile: int = 16,
 def ssd(x, dt, a, b, c, d_skip, *, chunk: int = 64,
         interpret: bool | None = None):
     """Mamba2 chunked SSD. x: (B,S,H,P); dt: (B,S,H); b/c: (B,S,G,N)."""
-    it = INTERPRET if interpret is None else interpret
+    it = interpret_mode() if interpret is None else interpret
     return ssd_pallas(x, dt, a, b, c, d_skip, chunk=chunk, interpret=it)

@@ -8,23 +8,26 @@ scatter/gather patterns that XLA lowers into long chains of small ops:
     occupancy of its co-location group: ``out[i] = Σ_j own[j] ·
     [groups[j] == groups[i]]``.  The lax reference is a ``segment_sum``
     followed by a gather; the kernel fuses both into one blocked
-    membership-matvec — a (blk, C) equality mask contracted against
-    ``own`` on the MXU, no (C,) totals round-trip through HBM.
+    membership reduction over a 2-D grid of (cell-row block, cell-column
+    block) tiles — each tile compares a ``(bj, 1)`` column of group ids
+    against a ``(1, bi)`` row and sums the matching ``own`` values down
+    the sublanes into the ``(1, bi)`` output row, so the mask tile stays
+    ``bj × bi`` whatever the fleet size.
 
 ``queue_admit``
     Admitting one tick's arrival burst into the per-cell FIFO ring
-    queues was a sequential ``fori_loop`` over arrival lanes (each lane
-    read-modify-writes ``q_len``).  The kernel re-derives each lane's
-    ring position *in closed form* — its FIFO rank among same-cell lanes
-    of the tick — so occupancy tests and position computation vectorize,
-    and only the final (provably conflict-free) element stores remain
-    serial.  A lane is admitted iff ``q_len0[cell] + rank < Q``, which
-    is exactly the sequential loop's outcome (test-enforced against a
-    host-side sequential reference over randomized bursts).
+    queues is sequential by nature: each lane reads and bumps its cell's
+    ``q_len``.  The kernel runs exactly that loop over the lanes, on the
+    scalar core (lane cells in SMEM, per-cell lengths as ``(C/128, 128)``
+    rows in VMEM, one dynamic-row read-modify-write per lane), and emits
+    each lane's position in its cell's queue (or -1 when it is dropped or
+    padding).  The ring-slot writes are then one conflict-free XLA
+    scatter.  The result is the sequential loop's (test-enforced against
+    the lax reference over randomized bursts).
 
-Both kernels run under ``interpret=True`` on CPU CI — the same code
-lowers to Mosaic on a real TPU by flipping ``INTERPRET`` (matching the
-``repro.kernels.ops`` convention for the seed LM kernels).
+Both kernels lower to Mosaic on a TPU backend and run under the Pallas
+interpreter elsewhere (the CPU test backend): :func:`interpret_mode`
+decides from ``jax.default_backend()``, and ``interpret=`` overrides it.
 """
 from __future__ import annotations
 
@@ -33,82 +36,96 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.analysis import envflags
-
-# CPU-only container default; a TPU deployment flips this via
-# REPRO_PALLAS_INTERPRET=0 (or passes interpret=False) and the same
-# kernels lower to Mosaic.  Shared with repro.kernels.ops.
-INTERPRET = envflags.bool_flag(envflags.PALLAS_INTERPRET, True)
-
-_GO_BLK = 128
+_LANES = 128
+_GO_ROW_BLK = 1024   # output cells per tile (lane axis)
+_GO_COL_BLK = 512    # summed-over cells per tile (sublane axis)
+_QA_LANE_BLK = 4096  # arrival lanes per SMEM block
+_SMEM_1D_TILE = 1024  # 1-D int32 SMEM blocks are laid out in 1024s
 
 
-def _group_occupancy_kernel(own_ref, g_all_ref, g_blk_ref, out_ref):
-    """One block of cells: out[i] = Σ_j own[j] · [g_j == g_i] as a
-    membership-mask matvec (MXU-friendly, no scatter)."""
-    own = own_ref[...]
-    eq = (g_blk_ref[...][:, None] == g_all_ref[...][None, :])
-    out_ref[...] = eq.astype(jnp.float32) @ own
+def interpret_mode() -> bool:
+    """True unless the default backend is a TPU: Mosaic lowers only
+    there, every other backend runs the kernels interpreted."""
+    return jax.default_backend() != "tpu"
 
 
-def group_occupancy_pallas(own, groups, *, blk: int = _GO_BLK,
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _group_occupancy_kernel(own_ref, g_col_ref, g_row_ref, out_ref):
+    """Tile (i, j): out[0, i-block] += Σ_{j in block} own[j] ·
+    [g_j == g_i], accumulated over the column grid axis."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    eq = g_col_ref[...] == g_row_ref[...]          # (bj, 1) vs (1, bi)
+    out_ref[...] += jnp.sum(jnp.where(eq, own_ref[...], 0.0), axis=0,
+                            keepdims=True)
+
+
+def group_occupancy_pallas(own, groups, *, blk: int = _GO_COL_BLK,
                            interpret: bool | None = None) -> jnp.ndarray:
     """Fused segment-sum + gather: (C,) own, (C,) int group ids in
-    [0, C) → (C,) per-cell group totals.  Exact for integer-valued
-    occupancies (counts ≤ 2^24 are exact in f32)."""
-    it = INTERPRET if interpret is None else interpret
+    [0, C) → (C,) per-cell group totals.  ``blk`` is the column block
+    (a multiple of 8); the row block is up to 1024 cells.  Exact for
+    integer-valued occupancies (counts ≤ 2^24 are exact in f32)."""
+    it = interpret_mode() if interpret is None else interpret
     c = own.shape[0]
-    cp = -(-c // blk) * blk
-    own_p = jnp.pad(own.astype(jnp.float32), (0, cp - c))
+    bi = min(_GO_ROW_BLK, _round_up(c, _LANES))
+    bj = min(blk, _round_up(c, 8))
+    ci, cj = _round_up(c, bi), _round_up(c, bj)
     groups = jnp.asarray(groups, jnp.int32)
     # pad ids so padded columns (-1) match nothing and padded rows (-2)
     # produce zeros that are sliced off below
-    g_cols = jnp.pad(groups, (0, cp - c), constant_values=-1)
-    g_rows = jnp.pad(groups, (0, cp - c), constant_values=-2)
+    own_col = jnp.pad(own.astype(jnp.float32), (0, cj - c)).reshape(cj, 1)
+    g_col = jnp.pad(groups, (0, cj - c), constant_values=-1).reshape(cj, 1)
+    g_row = jnp.pad(groups, (0, ci - c), constant_values=-2).reshape(1, ci)
     out = pl.pallas_call(
         _group_occupancy_kernel,
-        grid=(cp // blk,),
-        in_specs=[pl.BlockSpec((cp,), lambda i: (0,)),
-                  pl.BlockSpec((cp,), lambda i: (0,)),
-                  pl.BlockSpec((blk,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((blk,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((cp,), jnp.float32),
+        grid=(ci // bi, cj // bj),
+        in_specs=[pl.BlockSpec((bj, 1), lambda i, j: (j, 0)),
+                  pl.BlockSpec((bj, 1), lambda i, j: (j, 0)),
+                  pl.BlockSpec((1, bi), lambda i, j: (0, i))],
+        out_specs=pl.BlockSpec((1, bi), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, ci), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=it,
-    )(own_p, g_cols, g_rows)
-    return out[:c].astype(own.dtype)
+    )(own_col, g_col, g_row)
+    return out[0, :c].astype(own.dtype)
 
 
-def _queue_admit_kernel(qids_ref, qhead_ref, qlen_ref, rid_ref, cell_ref,
-                        valid_ref, qids_out, qlen_out, adm_ref, *, q: int):
-    rid = rid_ref[...]
-    cell = cell_ref[...]
-    valid = valid_ref[...]
-    a = rid.shape[0]
-    lane = jnp.arange(a)
-    # FIFO rank: earlier valid lanes of the same cell this tick.  The
-    # sequential loop admits the first (Q - q_len0) same-cell lanes and
-    # places lane r at ring slot head + q_len0 + r — closed form below.
-    same = (cell[:, None] == cell[None, :]) & valid[None, :]
-    rank = (same & (lane[None, :] < lane[:, None])).sum(-1)
-    qlen0 = qlen_ref[...]
-    c_safe = jnp.maximum(cell, 0)
-    ok = valid & (qlen0[c_safe] + rank < q)
-    pos = (qhead_ref[...][c_safe] + qlen0[c_safe] + rank) % q
-    adm_ref[...] = ok
-    n_cells = qlen0.shape[0]
-    per_cell = ((jnp.arange(n_cells)[:, None] == cell[None, :])
-                & ok[None, :]).sum(-1)
-    qlen_out[...] = qlen0 + per_cell.astype(jnp.int32)
-    qids_out[...] = qids_ref[...]
+def _queue_admit_kernel(cell_ref, len_ref, len_out, seen_ref, *,
+                        q: int, blk: int, a: int):
+    """Lanes of block b in order: a lane whose cell (-1 = invalid) has
+    room takes queue position ``len[cell]`` (written to ``seen``) and
+    bumps it; any other lane writes -1.  ``len_out`` stays resident
+    across the lane blocks."""
+    b = pl.program_id(0)
 
-    def store(i, _):
-        c, p = c_safe[i], pos[i]
-        cur = qids_out[c, p]
-        qids_out[c, p] = jnp.where(ok[i], rid[i], cur)
-        return 0
+    @pl.when(b == 0)
+    def _():
+        len_out[...] = len_ref[...]
 
-    jax.lax.fori_loop(0, a, store, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+
+    def admit(i, carry):
+        c = cell_ref[i]
+        cs = jnp.maximum(c, 0)
+        r = cs // _LANES
+        row = len_out[pl.ds(r, 1), :]
+        hit = lane == cs % _LANES
+        n = jnp.sum(jnp.where(hit, row, 0))
+        ok = (c >= 0) & (n < q)
+        seen_ref[i] = jnp.where(ok, n, -1)
+        len_out[pl.ds(r, 1), :] = jnp.where(hit & ok, row + 1, row)
+        return carry
+
+    jax.lax.fori_loop(0, jnp.minimum(blk, a - b * blk), admit, 0)
 
 
 def queue_admit_pallas(q_ids, q_head, q_len, rid, cell, valid,
@@ -120,26 +137,35 @@ def queue_admit_pallas(q_ids, q_head, q_len, rid, cell, valid,
     are padding or, under sharding, another shard's arrivals).
     Returns (q_ids', q_len', admitted (A,) bool) — identical to
     processing the lanes sequentially in order."""
+    it = interpret_mode() if interpret is None else interpret
     c, q = q_ids.shape
-    out = pl.pallas_call(
-        functools.partial(_queue_admit_kernel, q=q),
-        grid=(1,),
-        in_specs=[pl.BlockSpec((c, q), lambda i: (0, 0)),
-                  pl.BlockSpec((c,), lambda i: (0,)),
-                  pl.BlockSpec((c,), lambda i: (0,)),
-                  pl.BlockSpec(rid.shape, lambda i: (0,)),
-                  pl.BlockSpec(rid.shape, lambda i: (0,)),
-                  pl.BlockSpec(rid.shape, lambda i: (0,))],
-        out_specs=[pl.BlockSpec((c, q), lambda i: (0, 0)),
-                   pl.BlockSpec((c,), lambda i: (0,)),
-                   pl.BlockSpec(rid.shape, lambda i: (0,))],
-        out_shape=[jax.ShapeDtypeStruct((c, q), jnp.int32),
-                   jax.ShapeDtypeStruct((c,), jnp.int32),
-                   jax.ShapeDtypeStruct(rid.shape, jnp.bool_)],
-        interpret=INTERPRET if interpret is None else interpret,
-    )(q_ids, q_head, q_len, jnp.asarray(rid, jnp.int32),
-      jnp.asarray(cell, jnp.int32), valid)
-    return tuple(out)
+    a = rid.shape[0]
+    cell = jnp.where(valid, jnp.asarray(cell, jnp.int32), -1)
+    rows = _round_up(c, _LANES) // _LANES
+    len2 = jnp.pad(q_len, (0, rows * _LANES - c)).reshape(rows, _LANES)
+    blk = min(_QA_LANE_BLK, _round_up(a, _SMEM_1D_TILE))
+    ap = _round_up(a, blk)
+    smem_blk = pl.BlockSpec((blk,), lambda b: (b,),
+                            memory_space=pltpu.SMEM)
+    len_blk = pl.BlockSpec((rows, _LANES), lambda b: (0, 0))
+    len_out, seen = pl.pallas_call(
+        functools.partial(_queue_admit_kernel, q=q, blk=blk, a=a),
+        grid=(ap // blk,),
+        in_specs=[smem_blk, len_blk],
+        out_specs=[len_blk, smem_blk],
+        out_shape=[jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((ap,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=it,
+    )(jnp.pad(cell, (0, ap - a), constant_values=-1), len2)
+    seen = seen[:a]
+    admitted = seen >= 0
+    c_safe = jnp.maximum(cell, 0)
+    slot = (q_head[c_safe] + seen) % q
+    q_ids = q_ids.at[jnp.where(admitted, c_safe, c), slot].set(
+        jnp.asarray(rid, jnp.int32), mode="drop")
+    return q_ids, len_out.reshape(-1)[:c], admitted
 
 
 # ----------------------------------------------------------- references

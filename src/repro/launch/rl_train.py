@@ -31,6 +31,7 @@ from repro.core.baselines import DQLAgent, QLAgent
 from repro.env.edge_cloud import (EdgeCloudEnv, EnvConfig,
                                   brute_force_optimal, decision_string)
 from repro.env.scenarios import SCENARIOS, CONSTRAINTS
+from repro.launch.compile_cache import use_compile_cache
 from repro.policy.bundle import PolicyBundle, save_bundle
 from repro.specs.observation import SPEC_NAMES
 
@@ -119,6 +120,7 @@ def run_fleet(args):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--algo", choices=("HL", "DQL", "QL"), default="HL")
     ap.add_argument("--users", type=int, default=5)

@@ -79,6 +79,7 @@ import jax
 from repro.economy import PROFILE_NAMES, builtin_profile
 from repro.fleet.env import FleetConfig
 from repro.fleet.workload import poisson_round_trace, random_fleet
+from repro.launch.compile_cache import use_compile_cache
 from repro.policy.adapters import (heuristic_greedy_policy, slo_guarded,
                                    slo_guarded_params, solve_oracle)
 from repro.policy.api import Policy
@@ -331,6 +332,7 @@ def serve_bundle(bundle_path: str, *, rounds: int = 50, cells: int = 64,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--bundle", required=True,
                     help="PolicyBundle checkpoint (see rl_train --ckpt)")

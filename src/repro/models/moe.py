@@ -102,7 +102,6 @@ def apply_moe_shard_map(params, x, moe: MoEConfig, mesh_info,
                         capacity_factor: float | None = None):
     """Explicit-collective MoE. x: (B, S, D) → (y, aux_loss)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     b, s, d = x.shape
     k = moe.num_experts_per_tok
@@ -169,11 +168,11 @@ def apply_moe_shard_map(params, x, moe: MoEConfig, mesh_info,
             aux = jax.lax.pmean(aux, mean_axes)
         return y.reshape(bl, sl, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mi.mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, wd_spec),
         out_specs=(x_spec, P()),
-        check_rep=False)
+        check_vma=False)
     w = params["experts"]
     y, aux = fn(x, params["router"], w["w_gate"], w["w_up"], w["w_down"])
     if "shared" in params:

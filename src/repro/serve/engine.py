@@ -50,7 +50,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import io_callback
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.economy.tiers import (EconomyProfile, TierEconomyState,
@@ -295,11 +294,11 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
         def live_tick(st, ids, now):
 
             # -- 1. admit this tick's arrivals into the per-cell rings --
-            # one fused ring-scatter kernel per tick (rank-based closed
-            # form of the old sequential per-lane fori_loop; the lax
-            # reference *is* that loop, parity-tested).  The bucketer
-            # routes each arrival to its cell's shard, so valid lanes
-            # are always local here.
+            # the admission kernel runs the sequential per-lane loop and
+            # one scatter writes the ring slots (the lax reference is the
+            # same loop, parity-tested).  The bucketer routes each
+            # arrival to its cell's shard, so valid lanes are always
+            # local here.
             valid = ids >= 0
             c_loc = stream_cell[jnp.maximum(ids, 0)] - cell0
             admit_fn = (queue_admit_pallas if latency.USE_KERNELS
@@ -475,12 +474,12 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
             cur_ids=Pc, round_start=Pc, rec=Pc,
             tel=(MetricBuffer(edges=P(), hist=Pc, counters=Pc, gauges=Pc)
                  if cfg.telemetry else None))
-        run_epoch = shard_map(
+        run_epoch = jax.shard_map(
             run_epoch_body, mesh=mesh,
             in_specs=(P(), Pc, state_spec, P(None, CELLS_AXIS),
                       P(), P(), P(), P(), P()),
             out_specs=(state_spec, P()),
-            check_rep=False)
+            check_vma=False)
     else:
         run_epoch = run_epoch_body
 
@@ -531,6 +530,40 @@ def _tick_buckets(stream: RequestStream, tick_ms: float,
     now = (np.arange(T, dtype=np.float64) * tick_ms).astype(np.float32)
     live = np.arange(T) < n_ticks
     return ids, now, live, n_epochs
+
+
+def _stream_arrays(stream: RequestStream):
+    """The (N+1,)-padded per-request arrays every epoch reads: arrival
+    time, cell, SLO budget (slot N is the padded-lane scratch)."""
+    return (jnp.asarray(np.append(stream.t_ms, 0.0), jnp.float32),
+            jnp.asarray(np.append(stream.cell, 0), jnp.int32),
+            jnp.asarray(np.append(stream.slo_ms, 0.0), jnp.float32))
+
+
+def _n_windows(n_ticks: int, cfg: ServeConfig) -> int:
+    # windows cover the live serving ticks: the last live tick's wall
+    # clock decides the count, epoch padding can never add a window
+    return int((n_ticks - 1) * cfg.tick_ms // cfg.window_ms) + 1
+
+
+def first_epoch_args(engine: ServeEngine, policy: Policy, params,
+                     scenario: FleetScenario, stream: RequestStream,
+                     key) -> tuple:
+    """The arguments of ``engine.run_epoch`` for the stream's first
+    epoch, prepared as :func:`serve_stream` prepares them (``key`` seeds
+    the engine state) — to lower, compile or run one epoch program on
+    its own."""
+    cfg = engine.cfg
+    ticks_per_epoch = max(1, int(round(stream.epoch_ms / cfg.tick_ms)))
+    ids, now, live_ticks, _ = _tick_buckets(
+        stream, cfg.tick_ms, ticks_per_epoch, n_shards=engine.n_shards)
+    state = engine.init(key, scenario, stream.n_requests,
+                        _n_windows(int(live_ticks.sum()), cfg))
+    return (refresh_params(policy, params, scenario), scenario, state,
+            jnp.asarray(ids[:ticks_per_epoch]),
+            jnp.asarray(now[:ticks_per_epoch]),
+            jnp.asarray(live_ticks[:ticks_per_epoch]),
+            *_stream_arrays(stream))
 
 
 def serve_stream(policy: Policy, params, scenario: FleetScenario,
@@ -584,15 +617,9 @@ def serve_stream(policy: Policy, params, scenario: FleetScenario,
         stream, cfg.tick_ms, ticks_per_epoch, n_shards=S)
     N = stream.n_requests
     n_ticks = int(live_ticks.sum())
-    stream_t = jnp.asarray(np.append(stream.t_ms, 0.0), jnp.float32)
-    stream_cell = jnp.asarray(np.append(stream.cell, 0), jnp.int32)
-    stream_slo = jnp.asarray(np.append(stream.slo_ms, 0.0), jnp.float32)
-
-    # windows cover the live serving ticks: the last live tick's wall
-    # clock decides the count, epoch padding can never add a window
-    n_windows = int((n_ticks - 1) * cfg.tick_ms // cfg.window_ms) + 1
+    stream_t, stream_cell, stream_slo = _stream_arrays(stream)
     k_init, key = jax.random.split(key)
-    state = engine.init(k_init, scenario, N, n_windows)
+    state = engine.init(k_init, scenario, N, _n_windows(n_ticks, cfg))
     params_t = params
     wall, compile_wall, lanes, active = 0.0, 0.0, 0, 0
     for e in range(n_epochs):

@@ -10,9 +10,9 @@ execution, plus peak memory:
         steady_state_calls()
     prof.report()           # {compile_time_s, run_time_s, ...}
 
-Memory is the accelerator's ``peak_bytes_in_use`` when the backend
-exposes device memory stats (GPU/TPU), else the process peak RSS
-(``ru_maxrss``) — the field says which via ``memory_source``.
+Memory is the accelerator's ``peak_bytes_in_use`` on a device backend
+(GPU/TPU), and the process peak RSS (``ru_maxrss``) on the CPU backend —
+the field says which via ``memory_source``.
 
 Set ``REPRO_PROFILE_DIR`` (or pass ``trace_dir``) to additionally record
 a ``jax.profiler`` trace of the block for TensorBoard/Perfetto.
@@ -33,15 +33,18 @@ PROFILE_DIR_ENV = envflags.PROFILE_DIR
 
 
 def device_peak_memory_bytes() -> int | None:
-    """Accelerator peak allocation, when the backend reports it (CPU
-    backends return None)."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
+    """Peak allocation of the first local device.  The CPU backend keeps
+    no device statistics and gives None; any other backend must report
+    ``peak_bytes_in_use``, and raises if it does not — a host figure
+    never stands in for the device's."""
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
         return None
-    if not stats:
-        return None
-    return stats.get("peak_bytes_in_use")
+    stats = dev.memory_stats() or {}
+    if "peak_bytes_in_use" not in stats:
+        raise RuntimeError(f"{dev.platform} device {dev.device_kind!r} "
+                           f"reports no peak_bytes_in_use")
+    return int(stats["peak_bytes_in_use"])
 
 
 def host_peak_rss_bytes() -> int:

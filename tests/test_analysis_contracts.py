@@ -19,7 +19,6 @@ import numpy as np
 import pytest
 from jax.experimental import io_callback
 from jax.sharding import Mesh
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis import contracts
@@ -50,16 +49,17 @@ def _problems_of(contract):
 
 
 class TestPsumDrift:
-    def _toy(self, with_psum: bool, check_rep: bool = False):
+    def _toy(self, with_psum: bool, check_vma: bool = False):
         mesh = Mesh(np.asarray(jax.devices()[:1]), ("cells",))
 
         def body(x):
             y = x * 2.0
             return jax.lax.psum(y, "cells") if with_psum else y
 
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("cells"),
-                               out_specs=P() if with_psum else P("cells"),
-                               check_rep=check_rep))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("cells"),
+                                   out_specs=(P() if with_psum
+                                              else P("cells")),
+                                   check_vma=check_vma))
         return fn, (jnp.ones((4,), jnp.float32),)
 
     def test_added_psum_fails_check_with_named_contract(self):
@@ -82,9 +82,9 @@ class TestPsumDrift:
         assert c.collectives == {"psum": {"cells": 1}}
 
     def test_psum_cannot_hide_behind_check_rep(self):
-        # check_rep=True rewrites psum -> psum2 in the body jaxpr; the
-        # inventory must still count it as a cells-axis psum
-        c = _contract_of(*self._toy(True, check_rep=True), name="toy_psum")
+        # check_vma=True binds psum as psum_invariant in the body jaxpr;
+        # the inventory must still count it as a cells-axis psum
+        c = _contract_of(*self._toy(True, check_vma=True), name="toy_psum")
         assert c.psum_cells == 1
 
 
@@ -146,7 +146,7 @@ class TestDonationDrop:
 
 class TestF64Injection:
     def test_injected_f64_fails(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             fn = jax.jit(lambda x: x.astype(jnp.float64).sum())
             c = _contract_of(fn, (jnp.ones((4,), jnp.float32),),
                              name="toy_f64")

@@ -28,7 +28,7 @@ from repro.core.networks import init_mlp_net
 # ---------------------------------------------------------------- latency
 def test_latency_matches_numpy_reference_1000_cases():
     """≥1000 randomized (actions, background, weak-link) cases, 1e-5."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fn = jax.jit(jax.vmap(fl.response_times))
         acc_fn = jax.jit(fl.action_accuracy)
         rng = np.random.default_rng(0)

@@ -1,6 +1,6 @@
 """Orchestration-side Pallas kernel parity vs the lax references.
 
-The serve engine runs these kernels by default (interpret mode on CPU),
+The serve engine runs these kernels by default (interpreted on CPU),
 so exact agreement with the unfused references — ``segment_sum`` +
 gather for ``group_occupancy``, the sequential per-lane ``fori_loop``
 for ``queue_admit`` — is a correctness requirement, not a nicety:
@@ -37,7 +37,8 @@ needs_hypothesis = pytest.mark.skipif(not HAVE_HYPOTHESIS,
 def check_group_occupancy(c, n_groups, seed):
     k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
     own = jax.random.uniform(k1, (c,), jnp.float32, 0.0, 5.0)
-    groups = jax.random.randint(k2, (c,), 0, n_groups)
+    # the kernel's documented domain: group ids in [0, C)
+    groups = jax.random.randint(k2, (c,), 0, min(n_groups, c))
     got = group_occupancy_pallas(own, groups, interpret=True)
     want = group_occupancy_lax(own, groups)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -106,7 +107,6 @@ def test_latency_wrapper_kernel_matches_ref():
 def test_latency_axis_path_single_device_mesh():
     """The psum path (axis= under shard_map) reduces to the ref on a
     one-device cells mesh."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.runtime import CELLS_AXIS, cells_mesh
@@ -114,11 +114,11 @@ def test_latency_axis_path_single_device_mesh():
     mesh = cells_mesh(1)
     own = jax.random.uniform(jax.random.PRNGKey(5), (32,), jnp.float32)
     groups = jnp.arange(32) // 8
-    f = shard_map(
+    f = jax.shard_map(
         lambda o, g: latency.group_occupancy(o, g, axis=CELLS_AXIS,
                                              num_segments=32),
         mesh=mesh, in_specs=(P(CELLS_AXIS), P(CELLS_AXIS)),
-        out_specs=P(CELLS_AXIS), check_rep=False)
+        out_specs=P(CELLS_AXIS), check_vma=False)
     np.testing.assert_allclose(
         np.asarray(f(own, groups)),
         np.asarray(latency.group_occupancy_ref(own, groups)),
