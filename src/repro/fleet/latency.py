@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from repro.analysis import envflags
 from repro.env import latency_model as lm
@@ -74,17 +75,22 @@ def group_occupancy(own: jnp.ndarray, groups: jnp.ndarray, *,
     - otherwise: the fused Pallas kernel from
       ``repro.kernels.orchestration`` (default; ``REPRO_ORCH_KERNELS=0``
       falls back to :func:`group_occupancy_ref`).
+
+    Whichever path runs, its device operations carry the XLA frontend
+    attribute ``stage="occupancy"`` (over any enclosing stage), so a
+    profiler trace names edge-group occupancy whatever implements it.
     """
-    if axis is not None:
-        groups = jnp.asarray(groups)
-        n = groups.shape[0] if num_segments is None else num_segments
-        totals = jax.ops.segment_sum(own, groups, num_segments=n)
-        totals = jax.lax.psum(totals, axis)
-        return totals[groups]
-    if USE_KERNELS:
-        from repro.kernels.orchestration import group_occupancy_pallas
-        return group_occupancy_pallas(own, jnp.asarray(groups))
-    return group_occupancy_ref(own, groups, num_segments)
+    with set_xla_metadata(stage="occupancy"):
+        if axis is not None:
+            groups = jnp.asarray(groups)
+            n = groups.shape[0] if num_segments is None else num_segments
+            totals = jax.ops.segment_sum(own, groups, num_segments=n)
+            totals = jax.lax.psum(totals, axis)
+            return totals[groups]
+        if USE_KERNELS:
+            from repro.kernels.orchestration import group_occupancy_pallas
+            return group_occupancy_pallas(own, jnp.asarray(groups))
+        return group_occupancy_ref(own, groups, num_segments)
 
 
 def group_coupling(own: jnp.ndarray, groups: jnp.ndarray, *,

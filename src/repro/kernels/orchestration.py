@@ -95,6 +95,7 @@ def group_occupancy_pallas(own, groups, *, blk: int = _GO_COL_BLK,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=it,
+        name="group_occupancy",
     )(own_col, g_col, g_row)
     return out[0, :c].astype(own.dtype)
 
@@ -158,6 +159,7 @@ def queue_admit_pallas(q_ids, q_head, q_len, rid, cell, valid,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=it,
+        name="queue_admit",
     )(jnp.pad(cell, (0, ap - a), constant_values=-1), len2)
     seen = seen[:a]
     admitted = seen >= 0

@@ -43,13 +43,13 @@ with ``replay_trace`` at 1e-5.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import io_callback
+from jax.experimental.xla_metadata import set_xla_metadata
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.economy.tiers import (EconomyProfile, TierEconomyState,
@@ -63,6 +63,8 @@ from repro.policy.api import (Policy, act_batch, refresh_params,
 from repro.serve.metrics import request_report
 from repro.serve.stream import RequestStream
 from repro.sharding.runtime import CELLS_AXIS, get_mesh_info
+from repro.telemetry.profiling import (compile_counts, compiles_since,
+                                       recording, span)
 from repro.telemetry.metrics import (MetricBuffer, buffer_series,
                                      count_event, merge_shard_buffers,
                                      metrics_init, observe_values,
@@ -159,10 +161,13 @@ class EngineState(NamedTuple):
 class ServeEngine(NamedTuple):
     """``init(key, scenario, n_requests)`` and the jitted
     ``run_epoch(params, scenario, state, tick_ids, tick_now, stream_t,
-    stream_cell) -> (state', n_decisions)``.  ``n_shards`` is the cells-
-    mesh size the epoch step is shard_mapped over (1 = single device)."""
+    stream_cell) -> (state', n_decisions)``.  ``epoch_traces()`` is how
+    many times ``run_epoch``'s body has been traced.  ``n_shards`` is the
+    cells-mesh size the epoch step is shard_mapped over (1 = single
+    device)."""
     init: Callable
     run_epoch: Callable
+    epoch_traces: Callable[[], int]
     cfg: ServeConfig
     n_shards: int = 1
 
@@ -257,6 +262,8 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
             rec=RequestRecords(zf(), zf(), zf(), zb(), zb(), zb(), zi),
             tel=tel)
 
+    traces = [0]  # run_epoch_body's traces (a Python count, trace time)
+
     def run_epoch_body(params, scenario: FleetScenario, state: EngineState,
                        tick_ids, tick_now, tick_live, stream_t,
                        stream_cell, stream_slo):
@@ -276,6 +283,7 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
         scenario and queues are its C/S cells, ``tick_ids`` its (T_e, 1,
         A) arrival rows, and the record/telemetry copies its (1, N+1) /
         (1, W) slices — squeezed here, re-expanded on return."""
+        traces[0] += 1
         scratch = stream_t.shape[0] - 1  # slot N: padded-lane scatter sink
         # global id of this shard's first cell: local queue index =
         # stream cell id - cell0
@@ -292,6 +300,9 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
         params = refresh_params(policy, params, scenario)
 
         def live_tick(st, ids, now):
+            # each stage's device operations carry its name as an XLA
+            # frontend attribute (stage="admit", ...), which survives
+            # fusion and names the operation in a profiler trace
 
             # -- 1. admit this tick's arrivals into the per-cell rings --
             # the admission kernel runs the sequential per-lane loop and
@@ -299,43 +310,48 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
             # same loop, parity-tested).  The bucketer routes each
             # arrival to its cell's shard, so valid lanes are always
             # local here.
-            valid = ids >= 0
-            c_loc = stream_cell[jnp.maximum(ids, 0)] - cell0
-            admit_fn = (queue_admit_pallas if latency.USE_KERNELS
-                        else queue_admit_lax)
-            q_ids, q_len, admitted = admit_fn(
-                st.q_ids, st.q_head, st.q_len, ids, c_loc, valid)
-            rejected = valid & ~admitted
-            dropped = st.rec.dropped.at[
-                jnp.where(rejected, ids, scratch)].set(True)
-            n_adm = admitted.sum().astype(jnp.int32)
-            n_drop = rejected.sum().astype(jnp.int32)
+            with set_xla_metadata(stage="admit"):
+                valid = ids >= 0
+                c_loc = stream_cell[jnp.maximum(ids, 0)] - cell0
+                admit_fn = (queue_admit_pallas if latency.USE_KERNELS
+                            else queue_admit_lax)
+                q_ids, q_len, admitted = admit_fn(
+                    st.q_ids, st.q_head, st.q_len, ids, c_loc, valid)
+                rejected = valid & ~admitted
+                dropped = st.rec.dropped.at[
+                    jnp.where(rejected, ids, scratch)].set(True)
+                n_adm = admitted.sum().astype(jnp.int32)
+                n_drop = rejected.sum().astype(jnp.int32)
 
             # -- 2. form rounds at idle cells with backlog --
-            start = (st.cur_n == 0) & (q_len > 0)
-            n_new = jnp.where(start, jnp.minimum(q_len, n_max), 0)
-            pos = (st.q_head[:, None] + slot[None, :]) % Q
-            cand = jnp.take_along_axis(q_ids, pos, axis=1)
-            taken = slot[None, :] < n_new[:, None]
-            cur_ids = jnp.where(start[:, None],
-                                jnp.where(taken, cand, -1), st.cur_ids)
-            q_head = (st.q_head + n_new) % Q
-            q_len = q_len - n_new
-            cur_n = jnp.where(start, n_new, st.cur_n)
-            round_start = jnp.where(start, now, st.round_start)
+            with set_xla_metadata(stage="rounds"):
+                start = (st.cur_n == 0) & (q_len > 0)
+                n_new = jnp.where(start, jnp.minimum(q_len, n_max), 0)
+                pos = (st.q_head[:, None] + slot[None, :]) % Q
+                cand = jnp.take_along_axis(q_ids, pos, axis=1)
+                taken = slot[None, :] < n_new[:, None]
+                cur_ids = jnp.where(start[:, None],
+                                    jnp.where(taken, cand, -1), st.cur_ids)
+                q_head = (st.q_head + n_new) % Q
+                q_len = q_len - n_new
+                cur_n = jnp.where(start, n_new, st.cur_n)
+                round_start = jnp.where(start, now, st.round_start)
 
             # -- 3. one fleet-wide micro-batched decision + env step --
-            active = cur_n > 0
-            n_eff = jnp.maximum(cur_n, 1)
-            scn_t = scenario._replace(n_users=n_eff)
-            obs = env.observe(scn_t, st.env)
-            key, k_act = jax.random.split(st.key)
-            a = act_batch(policy, params, obs, k_act, n_users=n_eff)
-            # idle cells run a phantom 1-user round pinned to d0-local so
-            # they add no edge/cloud occupancy under shared couplings;
-            # their results are masked out of every record below
-            a = jnp.where(active, a, 0)
-            env2, _, _, done, info = env.step(scn_t, st.env, a)
+            with set_xla_metadata(stage="observe"):
+                active = cur_n > 0
+                n_eff = jnp.maximum(cur_n, 1)
+                scn_t = scenario._replace(n_users=n_eff)
+                obs = env.observe(scn_t, st.env)
+            with set_xla_metadata(stage="act"):
+                key, k_act = jax.random.split(st.key)
+                a = act_batch(policy, params, obs, k_act, n_users=n_eff)
+                # idle cells run a phantom 1-user round pinned to d0-local
+                # so they add no edge/cloud occupancy under shared
+                # couplings; their results are masked out of every record
+                a = jnp.where(active, a, 0)
+            with set_xla_metadata(stage="step"):
+                env2, _, _, done, info = env.step(scn_t, st.env, a)
 
             # -- 4. scatter per-request records for completed rounds --
             fin = done & active
@@ -346,90 +362,99 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
                 # advance the tier state machine: this tick's decisions
                 # may trigger cold starts (charged to their slot), idle
                 # tiers scale to zero, spot tiers preempt, µ$/mJ accrue
-                key, k_pre = jax.random.split(key)
-                u_cur = jnp.minimum(st.env.user, n_max - 1)
-                econ2, pen, ev = advance_economy(
-                    cfg.economy, st.env.econ, tick_ms=cfg.tick_ms,
-                    action=a, cursor=u_cur, active=active, now=now,
-                    round_start=round_start,
-                    round_actions=info["actions"], in_round=in_round,
-                    rec_mask=rec_mask, times=info["times"], fin=fin,
-                    key=k_pre,
-                    cell_ids=cell0 + jnp.arange(cur_n.shape[0]))
-                env2 = env2._replace(econ=econ2)
-                # completed requests waited out their tier's warmup: the
-                # wait lands in their service latency and the round's ART
-                pen_rec = jnp.where(rec_mask, pen, 0.0)
-                service = service + pen_rec
-                art = art + pen_rec.sum(-1) / n_eff.astype(jnp.float32)
-            rid = jnp.where(rec_mask, cur_ids, scratch)
-            flat = rid.reshape(-1)
-            wait_lanes = round_start[:, None] - stream_t[rid]
-            rec = st.rec._replace(dropped=dropped)
-            rec = rec._replace(
-                wait_ms=rec.wait_ms.at[flat].set(wait_lanes.reshape(-1)),
-                service_ms=rec.service_ms.at[flat].set(
-                    service.reshape(-1)),
-                art_ms=rec.art_ms.at[flat].set(
-                    jnp.broadcast_to(art[:, None],
-                                     rid.shape).reshape(-1)),
-                served=rec.served.at[flat].set(True),
-                violated=rec.violated.at[flat].set(
-                    jnp.broadcast_to(info["violated"][:, None],
-                                     rid.shape).reshape(-1)),
-                action=rec.action.at[flat].set(
-                    info["actions"].reshape(-1)))
+                with set_xla_metadata(stage="economy"):
+                    key, k_pre = jax.random.split(key)
+                    u_cur = jnp.minimum(st.env.user, n_max - 1)
+                    econ2, pen, ev = advance_economy(
+                        cfg.economy, st.env.econ, tick_ms=cfg.tick_ms,
+                        action=a, cursor=u_cur, active=active, now=now,
+                        round_start=round_start,
+                        round_actions=info["actions"], in_round=in_round,
+                        rec_mask=rec_mask, times=info["times"], fin=fin,
+                        key=k_pre,
+                        cell_ids=cell0 + jnp.arange(cur_n.shape[0]))
+                    env2 = env2._replace(econ=econ2)
+                    # completed requests waited out their tier's warmup:
+                    # the wait lands in their service latency and the
+                    # round's ART
+                    pen_rec = jnp.where(rec_mask, pen, 0.0)
+                    service = service + pen_rec
+                    art = art + pen_rec.sum(-1) / n_eff.astype(jnp.float32)
+            with set_xla_metadata(stage="scatter"):
+                rid = jnp.where(rec_mask, cur_ids, scratch)
+                flat = rid.reshape(-1)
+                wait_lanes = round_start[:, None] - stream_t[rid]
+                rec = st.rec._replace(dropped=dropped)
+                rec = rec._replace(
+                    wait_ms=rec.wait_ms.at[flat].set(
+                        wait_lanes.reshape(-1)),
+                    service_ms=rec.service_ms.at[flat].set(
+                        service.reshape(-1)),
+                    art_ms=rec.art_ms.at[flat].set(
+                        jnp.broadcast_to(art[:, None],
+                                         rid.shape).reshape(-1)),
+                    served=rec.served.at[flat].set(True),
+                    violated=rec.violated.at[flat].set(
+                        jnp.broadcast_to(info["violated"][:, None],
+                                         rid.shape).reshape(-1)),
+                    action=rec.action.at[flat].set(
+                        info["actions"].reshape(-1)))
 
             n_decisions = active.sum().astype(jnp.int32)
             tel = st.tel
             if cfg.telemetry:
                 # -- 5. per-window device accumulators (no host sync) --
-                w = window_of(tel, now, cfg.window_ms)
-                e2e = wait_lanes + service
-                attained = rec_mask & (e2e <= stream_slo[rid] + 1e-6)
-                for name, n in (
-                        ("admitted", n_adm), ("dropped", n_drop),
-                        ("decisions", n_decisions),
-                        ("served", rec_mask.sum()),
-                        ("violated", (rec_mask
-                                      & info["violated"][:, None]).sum()),
-                        ("attained", attained.sum())):
-                    tel = count_event(tel, name, w, n)
-                tel = observe_values(tel, e2e, rec_mask)
-                if cfg.economy is not None:
-                    # same integers as the run totals — the audit's
-                    # spend/energy conservation laws compare them exactly
-                    for name in ECON_COUNTERS:
-                        tel = count_event(tel, name, w, ev[name])
-                    for name in ECON_GAUGES:
-                        tel = set_gauge(tel, name, w, ev[name])
-                # window-end snapshots of queue/round/tier occupancy;
-                # tiers count this tick's committed slots of active rounds
-                acts = info["actions"]
-                decided = in_round & (acts >= 0)
-                for name, g in (
-                        ("backlog", q_len.sum()),
-                        ("queue_depth", q_len.mean()),
-                        ("inflight", jnp.where(active, cur_n, 0).sum()),
-                        ("occ_local", (decided
-                                       & (acts < latency.N_MODELS)).sum()),
-                        ("occ_edge", (decided
-                                      & (acts == latency.A_EDGE)).sum()),
-                        ("occ_cloud", (decided
-                                       & (acts == latency.A_CLOUD)).sum())):
-                    tel = set_gauge(tel, name, w, g)
-                if live is not None:
-                    # report this tick's window to the host; the window
-                    # is closed (final) once the next tick falls past it
-                    # — the driver's finish() flushes the last one
-                    w2 = window_of(tel, now + cfg.tick_ms, cfg.window_ms)
-                    io_callback(
-                        live.on_window, None, w, w2 > w, now,
-                        jnp.stack([tel.counters[n][w]
-                                   for n in counters]),
-                        jnp.stack([tel.gauges[n][w]
-                                   for n in gauges]),
-                        ordered=False)
+                with set_xla_metadata(stage="telemetry"):
+                    w = window_of(tel, now, cfg.window_ms)
+                    e2e = wait_lanes + service
+                    attained = rec_mask & (e2e <= stream_slo[rid] + 1e-6)
+                    for name, n in (
+                            ("admitted", n_adm), ("dropped", n_drop),
+                            ("decisions", n_decisions),
+                            ("served", rec_mask.sum()),
+                            ("violated",
+                             (rec_mask & info["violated"][:, None]).sum()),
+                            ("attained", attained.sum())):
+                        tel = count_event(tel, name, w, n)
+                    tel = observe_values(tel, e2e, rec_mask)
+                    if cfg.economy is not None:
+                        # same integers as the run totals — the audit's
+                        # spend/energy conservation laws compare them
+                        # exactly
+                        for name in ECON_COUNTERS:
+                            tel = count_event(tel, name, w, ev[name])
+                        for name in ECON_GAUGES:
+                            tel = set_gauge(tel, name, w, ev[name])
+                    # window-end snapshots of queue/round/tier
+                    # occupancy; tiers count this tick's committed slots
+                    # of active rounds
+                    acts = info["actions"]
+                    decided = in_round & (acts >= 0)
+                    for name, g in (
+                            ("backlog", q_len.sum()),
+                            ("queue_depth", q_len.mean()),
+                            ("inflight", jnp.where(active, cur_n, 0).sum()),
+                            ("occ_local", (decided
+                                           & (acts < latency.N_MODELS)).sum()),
+                            ("occ_edge", (decided
+                                          & (acts == latency.A_EDGE)).sum()),
+                            ("occ_cloud",
+                             (decided & (acts == latency.A_CLOUD)).sum())):
+                        tel = set_gauge(tel, name, w, g)
+                    if live is not None:
+                        # report this tick's window to the host; the
+                        # window is closed (final) once the next tick
+                        # falls past it — serve_stream's live.finish()
+                        # flushes the last one
+                        w2 = window_of(tel, now + cfg.tick_ms,
+                                       cfg.window_ms)
+                        io_callback(
+                            live.on_window, None, w, w2 > w, now,
+                            jnp.stack([tel.counters[n][w]
+                                       for n in counters]),
+                            jnp.stack([tel.gauges[n][w]
+                                       for n in gauges]),
+                            ordered=False)
 
             st2 = EngineState(
                 env=env2, key=key, q_ids=q_ids, q_head=q_head,
@@ -488,7 +513,7 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
     # support donation instead of being copied every chunk
     return ServeEngine(init=init,
                        run_epoch=jax.jit(run_epoch, donate_argnums=(2,)),
-                       cfg=cfg, n_shards=S)
+                       epoch_traces=lambda: traces[0], cfg=cfg, n_shards=S)
 
 
 def _tick_buckets(stream: RequestStream, tick_ms: float,
@@ -598,7 +623,23 @@ def serve_stream(policy: Policy, params, scenario: FleetScenario,
     single-device.  The cell count must divide evenly across the mesh.
     Per-shard record and telemetry copies are merged here before
     reporting, so the returned report is shard-count-invariant (and
-    ``report["mesh_cells"]`` records the shard count used)."""
+    ``report["mesh_cells"]`` records the shard count used).
+
+    The call's host work is recorded as spans (``repro.telemetry.span``,
+    which also show in an active ``jax.profiler`` trace):
+    ``serve.bucket`` (``_tick_buckets``), ``serve.arrays``
+    (``_stream_arrays``), ``serve.build`` (engine build and state init),
+    one ``serve.epoch`` per epoch — holding ``serve.refresh``
+    (``on_epoch``), ``serve.h2d`` (the epoch's tick slices to the
+    device), ``serve.dispatch`` (``run_epoch`` up to its return: trace,
+    lowering, cache lookup or compile, enqueue), ``serve.wait`` (until
+    its outputs are ready), ``serve.count`` (its decision count to the
+    host, from the second epoch on) and, with ``verbose`` or ``live``,
+    ``serve.progress`` — and ``serve.report``.  ``report["spans"]`` is
+    their ``SpanRecord.summary()``; ``compile_time_s`` is the first
+    ``serve.epoch`` and ``run_time_s`` the others.
+    ``report["counters"]`` holds ``epoch_traces`` (times this call traced
+    the epoch program) and the call's ``compiles_since`` counts."""
     if scenario.n_cells != stream.n_cells:
         raise ValueError(f"stream built for {stream.n_cells} cells, "
                          f"scenario has {scenario.n_cells}")
@@ -611,46 +652,96 @@ def serve_stream(policy: Policy, params, scenario: FleetScenario,
         raise ValueError(f"{scenario.n_cells} cells do not divide over "
                          f"the {S}-way {CELLS_AXIS!r} mesh")
     key = jax.random.PRNGKey(0) if key is None else key
-    engine = make_serve_engine(policy, cfg, live=live, mesh=mesh)
-    ticks_per_epoch = max(1, int(round(stream.epoch_ms / cfg.tick_ms)))
-    ids, now, live_ticks, n_epochs = _tick_buckets(
-        stream, cfg.tick_ms, ticks_per_epoch, n_shards=S)
+    compiles0 = compile_counts()
+    with recording() as spans:
+        ticks_per_epoch = max(1, int(round(stream.epoch_ms / cfg.tick_ms)))
+        with span("serve.bucket"):
+            ids, now, live_ticks, n_epochs = _tick_buckets(
+                stream, cfg.tick_ms, ticks_per_epoch, n_shards=S)
+        N = stream.n_requests
+        n_ticks = int(live_ticks.sum())
+        with span("serve.arrays"):
+            stream_t, stream_cell, stream_slo = _stream_arrays(stream)
+        with span("serve.build"):
+            engine = make_serve_engine(policy, cfg, live=live, mesh=mesh)
+            k_init, key = jax.random.split(key)
+            state = engine.init(k_init, scenario, N,
+                                _n_windows(n_ticks, cfg))
+        params_t, lanes, active = params, 0, 0
+        for e in range(n_epochs):
+            lo, hi = e * ticks_per_epoch, (e + 1) * ticks_per_epoch
+            with span("serve.epoch"):
+                with span("serve.refresh"):
+                    params_t = (refresh_params(policy, params, scenario)
+                                if on_epoch is None
+                                else on_epoch(e, params_t))
+                with span("serve.h2d") as h2d:
+                    ticks = (jnp.asarray(ids[lo:hi]),
+                             jnp.asarray(now[lo:hi]),
+                             jnp.asarray(live_ticks[lo:hi]))
+                with span("serve.dispatch"):
+                    out = engine.run_epoch(params_t, scenario, state,
+                                           *ticks, stream_t, stream_cell,
+                                           stream_slo)
+                # held on, the epoch's tick slices would stay on the
+                # device beside the next epoch's and raise its peak
+                del ticks
+                with span("serve.wait") as wait:
+                    state, n_act = jax.block_until_ready(out)
+                if e > 0:  # epoch 0 pays the XLA compile
+                    with span("serve.count"):
+                        lanes += scenario.n_cells * int(
+                            live_ticks[lo:hi].sum())
+                        active += int(n_act)
+                if verbose or live is not None:
+                    with span("serve.progress"):
+                        _progress(e, lo, hi, N, state, live, verbose,
+                                  wall_s=wait.end - h2d.start)
+
+        with span("serve.report"):
+            report = _report(stream, cfg, state, live, S, n_epochs,
+                             n_ticks)
+    summary = spans.summary()
+    epochs = summary["serve.epoch"]
+    # wall-clock split: epoch 0 carries the XLA compile (+ its ticks),
+    # the rest is steady-state execution
+    wall = epochs["total_s"] - epochs["first_s"]
+    report["compile_time_s"] = epochs["first_s"]
+    report["run_time_s"] = wall
+    # None when there is no steady-state window (single epoch)
+    report["decisions_per_s"] = (lanes / wall
+                                 if lanes and wall > 0 else None)
+    report["active_decisions_per_s"] = (active / wall
+                                        if active and wall > 0 else None)
+    report["spans"] = summary
+    report["counters"] = {"epoch_traces": engine.epoch_traces(),
+                          **compiles_since(compiles0)}
+    return report
+
+
+def _progress(e: int, lo: int, hi: int, n_requests: int,
+              state: EngineState, live, verbose: bool,
+              wall_s: float) -> None:
+    """The per-epoch progress record (``live``) and line (``verbose``)."""
+    done = int(np.asarray(state.rec.served)[:, :n_requests].any(0).sum())
+    backlog = int(np.asarray(state.q_len).sum())
+    if live is not None:
+        live.epoch(e, ticks=hi - lo, served=done, n_requests=n_requests,
+                   backlog=backlog,
+                   dropped=int(np.asarray(
+                       state.rec.dropped)[:, :n_requests].any(0).sum()),
+                   wall_s=round(wall_s, 4))
+    if verbose:
+        print(f"  epoch {e:3d}: ticks [{lo}, {hi}), "
+              f"{done:6d}/{n_requests} requests served, "
+              f"backlog {backlog}")
+
+
+def _report(stream: RequestStream, cfg: ServeConfig, state: EngineState,
+            live, S: int, n_epochs: int, n_ticks: int) -> dict:
+    """Merge the shard copies and build the report of a finished run
+    (everything but its timing, spans and counters)."""
     N = stream.n_requests
-    n_ticks = int(live_ticks.sum())
-    stream_t, stream_cell, stream_slo = _stream_arrays(stream)
-    k_init, key = jax.random.split(key)
-    state = engine.init(k_init, scenario, N, _n_windows(n_ticks, cfg))
-    params_t = params
-    wall, compile_wall, lanes, active = 0.0, 0.0, 0, 0
-    for e in range(n_epochs):
-        params_t = (refresh_params(policy, params, scenario)
-                    if on_epoch is None else on_epoch(e, params_t))
-        lo, hi = e * ticks_per_epoch, (e + 1) * ticks_per_epoch
-        t0 = time.perf_counter()
-        state, n_act = jax.block_until_ready(engine.run_epoch(
-            params_t, scenario, state, jnp.asarray(ids[lo:hi]),
-            jnp.asarray(now[lo:hi]), jnp.asarray(live_ticks[lo:hi]),
-            stream_t, stream_cell, stream_slo))
-        dt = time.perf_counter() - t0
-        if e > 0:  # epoch 0 pays the XLA compile
-            wall += dt
-            lanes += scenario.n_cells * int(live_ticks[lo:hi].sum())
-            active += int(n_act)
-        else:
-            compile_wall = dt
-        if verbose or live is not None:
-            done = int(np.asarray(state.rec.served)[:, :N].any(0).sum())
-            backlog = int(np.asarray(state.q_len).sum())
-            if live is not None:
-                live.epoch(e, ticks=hi - lo, served=done, n_requests=N,
-                           backlog=backlog,
-                           dropped=int(np.asarray(
-                               state.rec.dropped)[:, :N].any(0).sum()),
-                           wall_s=round(dt, 4))
-            if verbose:
-                print(f"  epoch {e:3d}: ticks [{lo}, {hi}), "
-                      f"{done:6d}/{N} requests served, "
-                      f"backlog {backlog}")
 
     # merge the per-shard record copies: each request has exactly one
     # writer (its cell's shard), so floats sum over the zero-initialized
@@ -670,15 +761,6 @@ def serve_stream(policy: Policy, params, scenario: FleetScenario,
     report["n_epochs"] = n_epochs
     report["n_ticks"] = n_ticks
     report["tick_ms"] = cfg.tick_ms
-    # wall-clock split: epoch 0 carries the XLA compile (+ its ticks),
-    # the rest is steady-state execution
-    report["compile_time_s"] = compile_wall
-    report["run_time_s"] = wall
-    # None when there is no steady-state window (single epoch)
-    report["decisions_per_s"] = (lanes / wall
-                                 if lanes and wall > 0 else None)
-    report["active_decisions_per_s"] = (active / wall
-                                        if active and wall > 0 else None)
     report["records"] = records
     if cfg.economy is not None:
         # lifetime per-cell integer totals (µ$ / mJ) summed over the

@@ -13,7 +13,9 @@
                cell and by action (``python -m repro.telemetry.report``)
     profiling  ``profiled()`` context wrapper: compile-vs-run wall-clock
                split, peak memory, optional ``jax.profiler`` trace dir
-               (``REPRO_PROFILE_DIR``) — the benchmarks report through it
+               (``REPRO_PROFILE_DIR``) — the benchmarks report through it;
+               ``span()`` host spans into the active ``recording()`` and
+               any profiler trace; a process-wide compile counter
     live       in-flight NDJSON export: ``LiveEmitter`` receives closed
                windows from inside the jitted scan via ``io_callback``
                and streams them with multi-window SLO burn-rate alerts
@@ -34,7 +36,9 @@ from repro.telemetry.metrics import (MetricBuffer, metrics_init,
                                      merge_shard_buffers)
 from repro.telemetry.trace import (build_trace, write_trace, read_trace,
                                    validate_trace)
-from repro.telemetry.profiling import Profile, profiled
+from repro.telemetry.profiling import (Profile, SpanRecord, compile_counts,
+                                       compiles_since, profiled, recording,
+                                       span)
 from repro.telemetry.live import (NdjsonSink, open_sink, BurnRateConfig,
                                   BurnRateAlerter, LiveEmitter,
                                   TrainLiveEmitter)
@@ -47,7 +51,8 @@ __all__ = [
     "observe_values", "buffer_series", "histogram_percentile",
     "histogram_percentiles", "merge_shard_buffers",
     "build_trace", "write_trace", "read_trace", "validate_trace",
-    "Profile", "profiled",
+    "Profile", "profiled", "SpanRecord", "recording", "span",
+    "compile_counts", "compiles_since",
     "NdjsonSink", "open_sink", "BurnRateConfig", "BurnRateAlerter",
     "LiveEmitter", "TrainLiveEmitter",
     "AuditResult", "audit_serve_report", "audit_trace",

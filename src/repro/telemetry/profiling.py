@@ -16,6 +16,22 @@ the field says which via ``memory_source``.
 
 Set ``REPRO_PROFILE_DIR`` (or pass ``trace_dir``) to additionally record
 a ``jax.profiler`` trace of the block for TensorBoard/Perfetto.
+
+``span(name)`` names a stretch of host work.  It always enters a
+``jax.profiler.TraceAnnotation``, so the span shows in any active
+profiler trace on the same clock as the device's operations, and it
+appends ``(name, start, end, parent)`` to the innermost active
+:class:`SpanRecord` (``recording()``), whose ``summary()`` is JSON-safe:
+
+    with recording() as rec:
+        with span("serve.epoch"):
+            with span("serve.wait"):
+                ...
+    rec.summary()   # {name: {n, total_s, self_s, first_s, p50_s}}
+
+``compile_counts()`` / ``compiles_since()`` read a process-wide counter
+of JAX's compile events (``jax.monitoring``): backend compiles, cache
+hits, and the seconds spent tracing, lowering and compiling.
 """
 from __future__ import annotations
 
@@ -23,6 +39,8 @@ import contextlib
 import dataclasses
 import os
 import resource
+import statistics
+import threading
 import time
 
 import jax
@@ -108,3 +126,162 @@ def profiled(label: str = "run", trace_dir: str | None = None):
             yield prof
         finally:
             prof._finalize()
+
+
+# ------------------------------------------------------------------ spans
+class SpanRecord:
+    """The spans entered while this record was active, in entry order:
+    ``[name, start, end, parent]`` on ``time.perf_counter()``, ``parent``
+    the index of the enclosing span here (-1 at the top), ``end`` None
+    while the span is open."""
+
+    def __init__(self):
+        self.spans: list = []
+        self._open: list = []   # indices of the spans entered, not exited
+
+    def summary(self) -> dict:
+        """``{name: {"n", "total_s", "self_s", "first_s", "p50_s"}}`` over
+        the closed spans.  Self time is a span's duration minus its
+        children's; ``first_s`` is its first occurrence, which carries
+        one-time costs (tracing, compiling); ``p50_s`` its median, which
+        neither those nor a rare stall move."""
+        child = [0.0] * len(self.spans)
+        for _, s, e, parent in self.spans:
+            if e is not None and parent >= 0:
+                child[parent] += e - s
+        out, durations = {}, {}
+        for (name, s, e, _), c in zip(self.spans, child):
+            if e is None:
+                continue
+            d = e - s
+            agg = out.get(name)
+            if agg is None:
+                out[name] = {"n": 1, "total_s": d, "self_s": d - c,
+                             "first_s": d}
+                durations[name] = [d]
+            else:
+                agg["n"] += 1
+                agg["total_s"] += d
+                agg["self_s"] += d - c
+                durations[name].append(d)
+        for name, agg in out.items():
+            agg["p50_s"] = statistics.median(durations[name])
+        return out
+
+
+_local = threading.local()
+
+
+def _records() -> list:
+    stack = getattr(_local, "records", None)
+    if stack is None:
+        stack = _local.records = []
+    return stack
+
+
+@contextlib.contextmanager
+def recording():
+    """Make a new :class:`SpanRecord` the innermost active one in this
+    thread for the block, and yield it."""
+    rec = SpanRecord()
+    stack = _records()
+    stack.append(rec)
+    try:
+        yield rec
+    finally:
+        stack.remove(rec)
+
+
+class Span:
+    """A span while it runs; ``start`` and ``end`` (``perf_counter``) are
+    set on entry and exit."""
+    __slots__ = ("name", "start", "end", "_ann", "_rec", "_i")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.start = self.end = None
+
+    def __enter__(self) -> "Span":
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        stack = _records()
+        self._rec = rec = stack[-1] if stack else None
+        self.start = time.perf_counter()
+        if rec is not None:
+            self._i = len(rec.spans)
+            rec.spans.append([self.name, self.start, None,
+                              rec._open[-1] if rec._open else -1])
+            rec._open.append(self._i)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = time.perf_counter()
+        rec = self._rec
+        if rec is not None:
+            rec.spans[self._i][2] = self.end
+            rec._open.pop()
+        self._ann.__exit__(*exc)
+
+
+def span(name: str) -> Span:
+    """``with span(name):`` — see the module docstring."""
+    return Span(name)
+
+
+# --------------------------------------------------------------- compiles
+# JAX reports every executable it builds as a backend-compile duration,
+# whether XLA compiled it or the persistent cache gave it back; a cache
+# read is also reported as a cache hit.  Backend compiles are the first
+# count minus the second.
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_SECONDS = {TRACE_EVENT: "trace_s", LOWER_EVENT: "lower_s",
+            COMPILE_EVENT: "compile_s"}
+
+_compiles = {"compile_events": 0, "cache_hits": 0,
+             "trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0}
+_compiles_lock = threading.Lock()
+_listening = False
+
+
+def _on_event(name, **_):
+    if name == CACHE_HIT_EVENT:
+        with _compiles_lock:
+            _compiles["cache_hits"] += 1
+
+
+def _on_duration(name, secs, **_):
+    key = _SECONDS.get(name)
+    if key is not None:
+        with _compiles_lock:
+            _compiles[key] += secs
+            if name == COMPILE_EVENT:
+                _compiles["compile_events"] += 1
+
+
+def compile_counts() -> dict:
+    """The process's compile totals since the first call (which starts
+    listening to ``jax.monitoring``): take one before a block and hand
+    it to :func:`compiles_since` after."""
+    global _listening
+    with _compiles_lock:
+        if not _listening:
+            jax.monitoring.register_event_listener(_on_event)
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _listening = True
+        return dict(_compiles)
+
+
+def compiles_since(before: dict) -> dict:
+    """JSON-safe counts since ``before`` (a :func:`compile_counts`):
+    ``backend_compiles`` (cache hits excluded), ``cache_hits``, and the
+    seconds spent tracing (``trace_s``), lowering (``lower_s``) and
+    compiling or loading from the cache (``compile_s``)."""
+    now = compile_counts()
+    d = {k: now[k] - before[k] for k in now}
+    return {"backend_compiles": d["compile_events"] - d["cache_hits"],
+            "cache_hits": d["cache_hits"], "trace_s": d["trace_s"],
+            "lower_s": d["lower_s"], "compile_s": d["compile_s"]}

@@ -101,6 +101,27 @@ def test_serve_epoch_compiles_with_mosaic_kernels(one_chip, monkeypatch):
     assert _custom_calls(compiled) >= 1
 
 
+def test_serve_epoch_kernels_carry_names_and_stage_tags(one_chip,
+                                                        monkeypatch):
+    """Compiled for the chip, the tick's kernel custom calls keep their
+    names and stage tags (XLA frontend attributes, which a profiler trace
+    prints with each operation): edge-group occupancy ``stage=
+    "occupancy"`` over the enclosing observe/step stage, queue admission
+    ``stage="admit"``."""
+    monkeypatch.setattr(orchestration, "interpret_mode", lambda: False)
+    fn, args, kwargs = registry._serve_build(registry._SERVE_SHARDED_CFG,
+                                             n_cells=256)
+    text = fn.lower(*_abstract(args, one_chip), **kwargs).compile().as_text()
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln]
+    occupancy = [ln for ln in calls if ln.startswith("%group_occupancy")]
+    admit = [ln for ln in calls if ln.startswith("%queue_admit")]
+    assert occupancy and admit
+    assert len(occupancy) + len(admit) == len(calls)
+    assert all('stage="occupancy"' in ln for ln in occupancy)
+    assert all('stage="admit"' in ln for ln in admit)
+
+
 def test_hltrain_run_compiles(one_chip):
     fn, (state, scenario, start), kwargs = registry._hltrain_build()
     compiled = fn.lower(_abstract(state, one_chip),
