@@ -43,6 +43,7 @@ with ``replay_trace`` at 1e-5.
 from __future__ import annotations
 
 import dataclasses
+from collections import OrderedDict
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -591,6 +592,41 @@ def first_epoch_args(engine: ServeEngine, policy: Policy, params,
             *_stream_arrays(stream))
 
 
+# engines serve_stream built, least recently used first: a later call
+# with an equal (policy, cfg, mesh) on a stream of the same shape reuses
+# the jitted programs, so it traces, lowers and looks up nothing again
+_ENGINES: OrderedDict = OrderedDict()
+_MAX_ENGINES = 4
+
+
+def _cached_engine(policy: Policy, cfg: ServeConfig, live,
+                   mesh: Optional[Mesh],
+                   shape: tuple) -> tuple[ServeEngine, bool]:
+    """The engine for ``(policy, cfg, mesh)`` and a stream of ``shape``,
+    and whether it was reused.  It is built by ``make_serve_engine`` on a
+    miss.  The shape is in the key, so each cached engine holds the
+    compiled programs of one stream shape and the cache bounds the
+    executables a process keeps, not only the engines.  ``live``
+    bypasses the cache: its emitter is per-run state the traced program
+    closes over.  Module state the epoch program reads while it is
+    traced (the functions of this module and of ``fleet.latency`` it
+    calls, ``latency.USE_KERNELS``) is not in the key either: a reused
+    engine serves the program traced when it was built, so a caller that
+    changes such state needs a new policy (``Policy`` compares its
+    callables by identity, and every constructor makes new ones)."""
+    if live is not None:
+        return make_serve_engine(policy, cfg, live=live, mesh=mesh), False
+    key = (policy, cfg, mesh, shape)
+    engine = _ENGINES.pop(key, None)
+    reused = engine is not None
+    if not reused:
+        engine = make_serve_engine(policy, cfg, mesh=mesh)
+    _ENGINES[key] = engine
+    while len(_ENGINES) > _MAX_ENGINES:
+        _ENGINES.popitem(last=False)
+    return engine, reused
+
+
 def serve_stream(policy: Policy, params, scenario: FleetScenario,
                  stream: RequestStream, cfg: ServeConfig, *, key=None,
                  on_epoch: Optional[Callable] = None,
@@ -598,7 +634,8 @@ def serve_stream(policy: Policy, params, scenario: FleetScenario,
                  mesh: Optional[Mesh] = None) -> dict:
     """Serve a :class:`RequestStream` end to end.  Returns the per-request
     report of ``repro.serve.metrics.request_report`` plus engine timing
-    (steady-state = excluding the compile-bearing first epoch):
+    (steady-state = excluding the first epoch, which on the first call
+    on an engine bears the compile):
     ``decisions_per_s`` counts every lane decided through ``Policy.act``
     — C per tick, phantom idle lanes included, the same accounting the
     round-replay gateway uses (C · n_max per round) so the two figures
@@ -625,11 +662,20 @@ def serve_stream(policy: Policy, params, scenario: FleetScenario,
     reporting, so the returned report is shard-count-invariant (and
     ``report["mesh_cells"]`` records the shard count used).
 
+    The engine is built once per equal ``(policy, cfg, mesh)`` and
+    stream shape (cell count, request count, largest tick, ticks per
+    epoch, window count) and kept for later calls (a few, least recently
+    used; see ``_cached_engine``); only ``init`` runs on every call, so
+    each call starts from fresh, donated state.  A call with ``live``
+    builds its own.  Only a caller that serves several streams of one
+    shape with the same policy object gains: ``serve_fleet`` makes one
+    call per policy.
+
     The call's host work is recorded as spans (``repro.telemetry.span``,
     which also show in an active ``jax.profiler`` trace):
     ``serve.bucket`` (``_tick_buckets``), ``serve.arrays``
-    (``_stream_arrays``), ``serve.build`` (engine build and state init),
-    one ``serve.epoch`` per epoch — holding ``serve.refresh``
+    (``_stream_arrays``), ``serve.build`` (engine build or reuse, and
+    state init), one ``serve.epoch`` per epoch — holding ``serve.refresh``
     (``on_epoch``), ``serve.h2d`` (the epoch's tick slices to the
     device), ``serve.dispatch`` (``run_epoch`` up to its return: trace,
     lowering, cache lookup or compile, enqueue), ``serve.wait`` (until
@@ -639,7 +685,8 @@ def serve_stream(policy: Policy, params, scenario: FleetScenario,
     their ``SpanRecord.summary()``; ``compile_time_s`` is the first
     ``serve.epoch`` and ``run_time_s`` the others.
     ``report["counters"]`` holds ``epoch_traces`` (times this call traced
-    the epoch program) and the call's ``compiles_since`` counts."""
+    the epoch program), ``engine_reused`` (1 if the engine came from an
+    earlier call) and the call's ``compiles_since`` counts."""
     if scenario.n_cells != stream.n_cells:
         raise ValueError(f"stream built for {stream.n_cells} cells, "
                          f"scenario has {scenario.n_cells}")
@@ -662,11 +709,15 @@ def serve_stream(policy: Policy, params, scenario: FleetScenario,
         n_ticks = int(live_ticks.sum())
         with span("serve.arrays"):
             stream_t, stream_cell, stream_slo = _stream_arrays(stream)
+        n_windows = _n_windows(n_ticks, cfg)
         with span("serve.build"):
-            engine = make_serve_engine(policy, cfg, live=live, mesh=mesh)
+            engine, reused = _cached_engine(
+                policy, cfg, live, mesh,
+                (scenario.n_cells, N, ids.shape[-1], ticks_per_epoch,
+                 n_windows))
+            traces0 = engine.epoch_traces()
             k_init, key = jax.random.split(key)
-            state = engine.init(k_init, scenario, N,
-                                _n_windows(n_ticks, cfg))
+            state = engine.init(k_init, scenario, N, n_windows)
         params_t, lanes, active = params, 0, 0
         for e in range(n_epochs):
             lo, hi = e * ticks_per_epoch, (e + 1) * ticks_per_epoch
@@ -688,7 +739,9 @@ def serve_stream(policy: Policy, params, scenario: FleetScenario,
                 del ticks
                 with span("serve.wait") as wait:
                     state, n_act = jax.block_until_ready(out)
-                if e > 0:  # epoch 0 pays the XLA compile
+                # the first epoch of the first call on an engine pays
+                # the XLA compile
+                if e > 0:
                     with span("serve.count"):
                         lanes += scenario.n_cells * int(
                             live_ticks[lo:hi].sum())
@@ -703,8 +756,8 @@ def serve_stream(policy: Policy, params, scenario: FleetScenario,
                              n_ticks)
     summary = spans.summary()
     epochs = summary["serve.epoch"]
-    # wall-clock split: epoch 0 carries the XLA compile (+ its ticks),
-    # the rest is steady-state execution
+    # wall-clock split: the first epoch (on the first call on an engine
+    # it carries the XLA compile) and the rest, steady-state execution
     wall = epochs["total_s"] - epochs["first_s"]
     report["compile_time_s"] = epochs["first_s"]
     report["run_time_s"] = wall
@@ -714,7 +767,8 @@ def serve_stream(policy: Policy, params, scenario: FleetScenario,
     report["active_decisions_per_s"] = (active / wall
                                         if active and wall > 0 else None)
     report["spans"] = summary
-    report["counters"] = {"epoch_traces": engine.epoch_traces(),
+    report["counters"] = {"epoch_traces": engine.epoch_traces() - traces0,
+                          "engine_reused": int(reused),
                           **compiles_since(compiles0)}
     return report
 

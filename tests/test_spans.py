@@ -4,12 +4,18 @@
   innermost-record routing, JSON-safe summary
 * ``serve_stream``: ``report["spans"]`` holds exactly the named spans,
   their self times fit in the call's wall time, ``compile_time_s`` /
-  ``run_time_s`` come from them; ``report["counters"]``: one epoch-program
-  trace per call and the backend compiles the benchmark's own counter
-  sees over the same call
+  ``run_time_s`` come from them; ``report["counters"]``: the epoch
+  program traced on the first call of an engine and reused after, and
+  the backend compiles the benchmark's own counter sees over the same
+  call
+* the engine cache: reuse across calls, an engine per stream shape with
+  the same results as a fresh engine, what bypasses it, its bounds, and
+  the traced program a reused engine keeps
 * the compiled epoch program carries a ``stage="…"`` frontend attribute
   for every tick stage
 """
+import dataclasses
+import io
 import json
 import re
 import sys
@@ -18,13 +24,16 @@ import types
 from pathlib import Path
 
 import jax
+import numpy as np
 import pytest
 
 from repro.fleet import FleetConfig, random_fleet
 from repro.policy import heuristic_greedy_policy
 from repro.serve import ServeConfig, poisson_request_stream, serve_stream
-from repro.serve.engine import first_epoch_args, make_serve_engine
-from repro.telemetry import profiling
+from repro.serve import engine as engine_mod
+from repro.serve.engine import (TEL_COUNTERS, TEL_GAUGES, _tick_buckets,
+                                first_epoch_args, make_serve_engine)
+from repro.telemetry import LiveEmitter, NdjsonSink, profiling
 from repro.telemetry.profiling import recording, span
 
 REPO = Path(__file__).resolve().parents[1]
@@ -138,10 +147,130 @@ def test_serve_stream_spans(verbose, capsys):
 
 
 def test_one_epoch_trace_per_call():
+    """The first call builds the engine and traces its epoch program; a
+    second call with the same policy and config reuses both."""
     pol, params, scn, stream, cfg = _case(telemetry=False)
-    for _ in range(2):
-        rep = serve_stream(pol, params, scn, stream, cfg)
-        assert rep["counters"]["epoch_traces"] == 1
+    counters = [serve_stream(pol, params, scn, stream, cfg)["counters"]
+                for _ in range(2)]
+    assert [c["epoch_traces"] for c in counters] == [1, 0]
+    assert [c["engine_reused"] for c in counters] == [0, 1]
+
+
+def _burst(stream, cfg):
+    tpe = round(stream.epoch_ms / cfg.tick_ms)
+    return _tick_buckets(stream, cfg.tick_ms, tpe)[0].shape[-1]
+
+
+def _assert_same_result(rep, want):
+    assert rep["records"].keys() == want["records"].keys()
+    for k, v in want["records"].items():
+        np.testing.assert_array_equal(rep["records"][k], v, err_msg=k)
+    assert rep.get("telemetry") == want.get("telemetry")
+
+
+def test_new_stream_shape_gets_an_engine_of_its_own():
+    """A stream of another request count N and burst A builds and traces
+    an engine of its own, which serves it as a freshly built engine does,
+    the same on every call; the engine of the first shape stays cached."""
+    pol, params, scn, stream, cfg = _case()
+    serve_stream(pol, params, scn, stream, cfg)
+    other = poisson_request_stream(jax.random.PRNGKey(7), scn, 3000.0,
+                                   rate=2.0, round_ms=cfg.round_ms,
+                                   epoch_ms=500.0)
+    assert other.n_requests != stream.n_requests
+    assert _burst(other, cfg) != _burst(stream, cfg)
+    key = jax.random.PRNGKey(8)
+    reps = [serve_stream(pol, params, scn, other, cfg, key=key)
+            for _ in range(2)]
+    assert [r["counters"]["epoch_traces"] for r in reps] == [1, 0]
+    assert [r["counters"]["engine_reused"] for r in reps] == [0, 1]
+    back = serve_stream(pol, params, scn, stream, cfg)["counters"]
+    assert (back["epoch_traces"], back["engine_reused"]) == (0, 1)
+    fresh_pol = heuristic_greedy_policy(FleetConfig(n_max=4).spec())
+    fresh = serve_stream(fresh_pol, fresh_pol.init(jax.random.PRNGKey(2)),
+                         scn, other, cfg, key=key)
+    assert fresh["counters"]["engine_reused"] == 0
+    assert "telemetry" in fresh
+    for rep in reps:
+        _assert_same_result(rep, fresh)
+
+
+def test_engine_cache_bounds_the_programs_kept():
+    """Three streams of different N with one policy give three engines,
+    and every cached engine has traced its epoch program once: one
+    compiled program a shape, and at most ``_MAX_ENGINES`` of them."""
+    pol, params, scn, _, cfg = _case(telemetry=False)
+    for horizon_ms in (1000.0, 1500.0, 2500.0):
+        stream = poisson_request_stream(jax.random.PRNGKey(3), scn,
+                                        horizon_ms, rate=1.0,
+                                        round_ms=cfg.round_ms,
+                                        epoch_ms=500.0)
+        c = serve_stream(pol, params, scn, stream, cfg)["counters"]
+        assert (c["epoch_traces"], c["engine_reused"]) == (1, 0)
+    mine = [e for k, e in engine_mod._ENGINES.items() if k[0] is pol]
+    assert len(mine) == 3
+    assert len(engine_mod._ENGINES) <= engine_mod._MAX_ENGINES
+    assert all(e.epoch_traces() == 1
+               for e in engine_mod._ENGINES.values())
+
+
+@pytest.mark.parametrize("change", ["live", "cfg", "policy"])
+def test_engine_not_reused(change):
+    """A call with a live emitter, with another config, or with a policy
+    built anew (equal in kind, new callables) builds its own engine."""
+    pol, params, scn, stream, cfg = _case()
+    serve_stream(pol, params, scn, stream, cfg)
+    kw = {}
+    if change == "live":
+        kw["live"] = LiveEmitter(NdjsonSink(io.StringIO()), TEL_COUNTERS,
+                                 TEL_GAUGES, window_ms=cfg.window_ms)
+    elif change == "cfg":
+        cfg = dataclasses.replace(cfg, queue_cap=32)
+    else:
+        pol = heuristic_greedy_policy(FleetConfig(n_max=4).spec())
+    rep = serve_stream(pol, params, scn, stream, cfg, **kw)
+    assert rep["counters"]["engine_reused"] == 0
+    assert rep["counters"]["epoch_traces"] == 1
+
+
+def test_reused_engine_keeps_the_program_it_traced(monkeypatch):
+    """Module state read while the epoch program is traced is not in the
+    cache key: after ``act_batch`` is patched, the same policy serves the
+    program traced before, and a policy built anew traces the patch."""
+    pol, params, scn, stream, cfg = _case(telemetry=False)
+    base = serve_stream(pol, params, scn, stream, cfg)
+    orig = engine_mod.act_batch
+
+    def altered(policy, params, obs, key, n_users=None):
+        a = orig(policy, params, obs, key, n_users=n_users)
+        return a.at[0].set((a[0] + 1) % 10)
+    monkeypatch.setattr(engine_mod, "act_batch", altered)
+    same = serve_stream(pol, params, scn, stream, cfg)
+    assert same["counters"]["engine_reused"] == 1
+    _assert_same_result(same, base)
+    new_pol = heuristic_greedy_policy(FleetConfig(n_max=4).spec())
+    new = serve_stream(new_pol, params, scn, stream, cfg)
+    assert new["counters"]["engine_reused"] == 0
+    assert not np.array_equal(new["records"]["action"],
+                              base["records"]["action"])
+
+
+def test_engine_cache_keeps_the_most_recent_few():
+    """The cache holds a bounded number of engines and lets the least
+    recently used one go first."""
+    _, params, scn, stream, cfg = _case(cells=4, horizon_ms=600.0,
+                                        telemetry=False)
+    spec = FleetConfig(n_max=4).spec()
+    pols = [heuristic_greedy_policy(spec)
+            for _ in range(engine_mod._MAX_ENGINES + 1)]
+    serve = lambda p: serve_stream(p, params, scn, stream, cfg)[
+        "counters"]["engine_reused"]
+    assert [serve(p) for p in pols[:-1]] == [0] * (len(pols) - 1)
+    assert serve(pols[0]) == 1          # now the most recently used
+    assert serve(pols[-1]) == 0         # evicts pols[1]
+    assert len(engine_mod._ENGINES) == engine_mod._MAX_ENGINES
+    assert serve(pols[0]) == 1
+    assert serve(pols[1]) == 0
 
 
 def test_backend_compiles_match_the_benchmark_counter():
