@@ -43,6 +43,7 @@ from repro.env.edge_cloud import (PENALTY_BASE, PENALTY_PER_PCT,
                                   REWARD_SCALE)
 from repro.fleet import latency
 from repro.fleet.workload import FleetScenario
+from repro.sharding.runtime import exchange_psum
 from repro.specs.observation import ObsInputs, ObservationSpec, make_spec
 
 
@@ -69,9 +70,10 @@ class FleetConfig:
     # cells: ``cell_axis`` names the axis and ``cell_axis_size`` its size.
     # The env then treats ``scenario.n_cells`` as the per-shard count and
     # reduces the cross-cell couplings (shared cloud occupancy, edge-group
-    # occupancy, fleet-wide load aggregates) with ``psum`` over that axis,
-    # so a sharded fleet is numerically identical to the same fleet on one
-    # device (background draws are keyed per *global* cell id).
+    # occupancy, fleet-wide load aggregates) with ``psum`` over that axis
+    # (``exchange_psum``: tagged ``stage="exchange"``), so a sharded fleet
+    # is numerically identical to the same fleet on one device
+    # (background draws are keyed per *global* cell id).
     cell_axis: str | None = None
     cell_axis_size: int = 1
     # Optional tier economics (repro.economy): when set, ``init`` seeds a
@@ -183,7 +185,7 @@ def make_fleet_env(cfg: FleetConfig) -> FleetEnvFns:
         """Sum over all cells of the fleet, across shards when sharded."""
         total = x.sum()
         if cfg.cell_axis is not None:
-            total = jax.lax.psum(total, cfg.cell_axis)
+            total = exchange_psum(total, cfg.cell_axis)
         return total
 
     def _cloud_coupling(actions, mask):

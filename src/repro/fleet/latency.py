@@ -23,6 +23,7 @@ from jax.experimental.xla_metadata import set_xla_metadata
 
 from repro.analysis import envflags
 from repro.env import latency_model as lm
+from repro.sharding.runtime import exchange_psum
 
 N_MODELS = lm.N_MODELS
 N_ACTIONS = lm.N_ACTIONS
@@ -78,14 +79,15 @@ def group_occupancy(own: jnp.ndarray, groups: jnp.ndarray, *,
 
     Whichever path runs, its device operations carry the XLA frontend
     attribute ``stage="occupancy"`` (over any enclosing stage), so a
-    profiler trace names edge-group occupancy whatever implements it.
+    profiler trace names edge-group occupancy whatever implements it;
+    the cross-shard ``psum`` is tagged ``stage="exchange"`` instead.
     """
     with set_xla_metadata(stage="occupancy"):
         if axis is not None:
             groups = jnp.asarray(groups)
             n = groups.shape[0] if num_segments is None else num_segments
             totals = jax.ops.segment_sum(own, groups, num_segments=n)
-            totals = jax.lax.psum(totals, axis)
+            totals = exchange_psum(totals, axis)
             return totals[groups]
         if USE_KERNELS:
             from repro.kernels.orchestration import group_occupancy_pallas

@@ -51,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import io_callback
 from jax.experimental.xla_metadata import set_xla_metadata
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.economy.tiers import (EconomyProfile, TierEconomyState,
                                  advance_economy)
@@ -63,7 +63,7 @@ from repro.policy.api import (Policy, act_batch, refresh_params,
                               require_jittable)
 from repro.serve.metrics import request_report
 from repro.serve.stream import RequestStream
-from repro.sharding.runtime import CELLS_AXIS, get_mesh_info
+from repro.sharding.runtime import CELLS_AXIS, exchange_psum, get_mesh_info
 from repro.telemetry.profiling import (compile_counts, compiles_since,
                                        recording, span)
 from repro.telemetry.metrics import (MetricBuffer, buffer_series,
@@ -186,7 +186,8 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
     ``shard_map``-ped over it — each device owns ``C / S`` cells' queues,
     env state, and record/telemetry copies, and only the cross-cell
     couplings (shared-cloud occupancy, edge-group occupancy, fleet load
-    aggregates) and the decision count cross shards, via ``psum``.
+    aggregates) and the decision count cross shards, via ``psum``
+    (``exchange_psum``: tagged ``stage="exchange"``).
     Because the env keys background draws by *global* cell id and the
     PRNG key is replicated, the sharded engine is numerically identical
     to the single-device one for deterministic-per-cell policies (the
@@ -207,6 +208,24 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
             raise ValueError("live streaming (io_callback) is not "
                              "supported under a cells mesh — run the "
                              "live serve single-device")
+        Pc = P(CELLS_AXIS)
+        # pytree-prefix specs: a bare spec at a subtree position covers
+        # all its leaves.  Replicated: params, PRNG keys, the stream
+        # arrays, tick times, histogram edges.  Sharded over cells: the
+        # scenario, queues, env state, and the per-shard record /
+        # telemetry copies (their leading S axis *is* the mesh axis).
+        state_spec = EngineState(
+            env=FleetState(key=P(), actions=Pc, user=Pc, charged=Pc,
+                           bg=Pc,
+                           econ=(Pc if cfg.economy is not None else None)),
+            key=P(), q_ids=Pc, q_head=Pc, q_len=Pc, cur_n=Pc,
+            cur_ids=Pc, round_start=Pc, rec=Pc,
+            tel=(MetricBuffer(edges=P(), hist=Pc, counters=Pc, gauges=Pc)
+                 if cfg.telemetry else None))
+        # init places the state as run_epoch returns it, so that every
+        # epoch, the first too, calls one program
+        state_place = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                   state_spec)
     S = int(mesh.shape[CELLS_AXIS]) if sharded else 1
     env = make_fleet_env(cfg.fleet(CELLS_AXIS if sharded else None, S))
     # init runs outside shard_map (no axis to query): a mesh-free twin
@@ -251,7 +270,7 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
                 edges=t0.edges, hist=tile(t0.hist),
                 counters={n: tile(v) for n, v in t0.counters.items()},
                 gauges={n: tile(v) for n, v in t0.gauges.items()})
-        return EngineState(
+        state = EngineState(
             env=env_init.init(k_env, scenario),
             key=key,
             q_ids=jnp.full((C, Q), -1, jnp.int32),
@@ -262,6 +281,7 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
             round_start=jnp.zeros((C,), jnp.float32),
             rec=RequestRecords(zf(), zf(), zf(), zb(), zb(), zb(), zi),
             tel=tel)
+        return jax.device_put(state, state_place) if sharded else state
 
     traces = [0]  # run_epoch_body's traces (a Python count, trace time)
 
@@ -479,27 +499,13 @@ def make_serve_engine(policy: Policy, cfg: ServeConfig,
             tick, st0, (tick_ids[:, 0], tick_now, tick_live))
         n = n_act.sum()
         if sharded:
-            n = jax.lax.psum(n, CELLS_AXIS)
+            n = exchange_psum(n, CELLS_AXIS)
         st1 = st1._replace(
             rec=jax.tree.map(lambda x: x[None], st1.rec),
             tel=(_expand_tel(st1.tel) if cfg.telemetry else None))
         return st1, n
 
     if sharded:
-        Pc = P(CELLS_AXIS)
-        # pytree-prefix specs: a bare spec at a subtree position covers
-        # all its leaves.  Replicated: params, PRNG keys, the stream
-        # arrays, tick times, histogram edges.  Sharded over cells: the
-        # scenario, queues, env state, and the per-shard record /
-        # telemetry copies (their leading S axis *is* the mesh axis).
-        state_spec = EngineState(
-            env=FleetState(key=P(), actions=Pc, user=Pc, charged=Pc,
-                           bg=Pc,
-                           econ=(Pc if cfg.economy is not None else None)),
-            key=P(), q_ids=Pc, q_head=Pc, q_len=Pc, cur_n=Pc,
-            cur_ids=Pc, round_start=Pc, rec=Pc,
-            tel=(MetricBuffer(edges=P(), hist=Pc, counters=Pc, gauges=Pc)
-                 if cfg.telemetry else None))
         run_epoch = jax.shard_map(
             run_epoch_body, mesh=mesh,
             in_specs=(P(), Pc, state_spec, P(None, CELLS_AXIS),
@@ -524,7 +530,8 @@ def _tick_buckets(stream: RequestStream, tick_ms: float,
     mesh — by the shard owning their cell (shard ``s`` holds cells
     ``[s·C/S, (s+1)·C/S)``, matching the mesh's block partition of the
     scenario).  Returns (T, S, A) -1-padded id rows (A = the max
-    per-tick-per-shard burst; within a row ids stay in arrival order, so
+    per-tick-per-shard burst, under a mesh rounded up to 1/8 of its power
+    of two; within a row ids stay in arrival order, so
     per-cell FIFO admission order is shard-invariant), the (T,) tick
     times, the (T,) live-tick mask, and the epoch count.
 
@@ -547,6 +554,12 @@ def _tick_buckets(stream: RequestStream, tick_ms: float,
     counts = np.bincount((tick_of * n_shards + shard_of)[ok],
                          minlength=T * n_shards)
     A = max(1, int(counts.max()) if counts.size else 1)
+    if n_shards > 1:
+        # a shard's largest burst varies from stream to stream even where
+        # the fleet's is fixed: round it up to an eighth of its power of
+        # two, so streams of one rate share one compiled program
+        q = 1 << max(0, A.bit_length() - 4)
+        A = -(-A // q) * q
     ids = np.full((T, n_shards, A), -1, np.int32)
     cursor = np.zeros((T, n_shards), np.int64)
     for i in np.nonzero(ok)[0]:
@@ -681,12 +694,14 @@ def serve_stream(policy: Policy, params, scenario: FleetScenario,
     lowering, cache lookup or compile, enqueue), ``serve.wait`` (until
     its outputs are ready), ``serve.count`` (its decision count to the
     host, from the second epoch on) and, with ``verbose`` or ``live``,
-    ``serve.progress`` — and ``serve.report``.  ``report["spans"]`` is
-    their ``SpanRecord.summary()``; ``compile_time_s`` is the first
-    ``serve.epoch`` and ``run_time_s`` the others.
-    ``report["counters"]`` holds ``epoch_traces`` (times this call traced
-    the epoch program), ``engine_reused`` (1 if the engine came from an
-    earlier call) and the call's ``compiles_since`` counts."""
+    ``serve.progress`` — and ``serve.report``, holding ``serve.merge``
+    (the shard copies of records and telemetry to the host, merged).
+    ``report["spans"]`` is their ``SpanRecord.summary()``;
+    ``compile_time_s`` is the first ``serve.epoch`` and ``run_time_s``
+    the others.  ``report["counters"]`` holds ``epoch_traces`` (times
+    this call traced the epoch program), ``engine_reused`` (1 if the
+    engine came from an earlier call) and the call's ``compiles_since``
+    counts."""
     if scenario.n_cells != stream.n_cells:
         raise ValueError(f"stream built for {stream.n_cells} cells, "
                          f"scenario has {scenario.n_cells}")
@@ -808,8 +823,14 @@ def _report(stream: RequestStream, cfg: ServeConfig, state: EngineState,
             return v.max(axis=0)
         return v.sum(axis=0)
 
-    records = {k: _merge_rec(k, v)[:N] for k, v in
-               state.rec._asdict().items()}
+    with span("serve.merge"):
+        records = {k: _merge_rec(k, v)[:N] for k, v in
+                   state.rec._asdict().items()}
+        # shards partition the cells, so counters/histogram sum; gauges
+        # are extensive totals except queue_depth, a per-cell mean
+        tel = (merge_shard_buffers(state.tel,
+                                   gauge_reduce={"queue_depth": "mean"})
+               if cfg.telemetry else None)
     report = request_report(stream, records)
     report["mesh_cells"] = S
     report["n_epochs"] = n_epochs
@@ -837,10 +858,6 @@ def _report(stream: RequestStream, cfg: ServeConfig, state: EngineState,
                                    if n_served else None),
         }
     if cfg.telemetry:
-        # shards partition the cells, so counters/histogram sum; gauges
-        # are extensive totals except queue_depth, a per-cell mean
-        tel = merge_shard_buffers(state.tel,
-                                  gauge_reduce={"queue_depth": "mean"})
         report["telemetry"] = telemetry_report(tel, cfg.window_ms)
         if live is not None:
             live.finish(report["telemetry"])

@@ -14,6 +14,9 @@ and falls back to the mesh-free path when None (single-device tests).
 The two axis vocabularies never mix: a ``cells`` mesh carries no dp/tp
 axes and vice versa, so ``dp_spec``/``tp_size`` keep their seed LM
 semantics untouched.
+
+Every reduction across the ``cells`` axis goes through
+:func:`exchange_psum`, which tags it for a profiler trace.
 """
 from __future__ import annotations
 
@@ -21,9 +24,18 @@ import dataclasses
 from typing import Optional
 
 import jax
+from jax.experimental.xla_metadata import set_xla_metadata
 from jax.sharding import Mesh
 
 CELLS_AXIS = "cells"
+
+
+def exchange_psum(x, axis: str):
+    """``psum`` of ``x`` over the mesh axis ``axis``, its device
+    operations tagged ``stage="exchange"`` (over any enclosing stage), so
+    a profiler trace names the cross-shard exchange."""
+    with set_xla_metadata(stage="exchange"):
+        return jax.lax.psum(x, axis)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -13,9 +13,22 @@ Acceptance contract of the cell-sharded engine:
   * ``MeshInfo`` carries the new axis without disturbing the seed LM
     dp/tp detection, and ``serve_stream`` picks a registered cells mesh
     up from the sharding runtime registry
+  * under a mesh a shard's bucket width is its largest burst rounded up
+    to an eighth of its power of two, so streams of one rate share one
+    compiled program
   * ``merge_shard_buffers`` reduces per-shard MetricBuffer copies with
     per-name gauge semantics (sum vs mean) and NaN-safe windows
+  * the benchmark's four-chip deployment, cut to 64 cells on four forced
+    host devices, passes the plain reference under its cell's limits;
+    its epoch program is traced once a build (the initial state is
+    placed on the mesh as the epochs return it), tags every cells-axis
+    psum ``stage="exchange"`` (the single-device program carries no such
+    tag), compiles to all-reduces of the bytes the benchmark's reader
+    counts, and its report records the shard merge as ``serve.merge``
+    inside ``serve.report``
 """
+import contextlib
+import json
 import os
 import subprocess
 import sys
@@ -28,7 +41,8 @@ import pytest
 from repro.fleet import FleetConfig, random_fleet
 from repro.policy import dqn_policy, heuristic_greedy_policy
 from repro.serve import ServeConfig, poisson_request_stream, serve_stream
-from repro.serve.engine import make_serve_engine
+from repro.serve.engine import _tick_buckets, make_serve_engine
+from repro.serve.stream import RequestStream
 from repro.sharding.runtime import (CELLS_AXIS, cells_mesh, get_mesh_info,
                                     set_mesh_info)
 from repro.telemetry import (MetricBuffer, audit_serve_report, build_trace,
@@ -261,6 +275,33 @@ def test_serve_stream_picks_up_registry_mesh():
 
 
 # ------------------------------------------------- merge_shard_buffers
+def _burst_stream(burst: int) -> RequestStream:
+    """``burst`` requests to cell 0 in the first tick, one to each other
+    cell of an 8-cell fleet in the second."""
+    t = np.r_[np.full(burst, 10.0), np.full(7, 60.0)].astype(np.float32)
+    cell = np.r_[np.zeros(burst), np.arange(1, 8)].astype(np.int32)
+    return RequestStream(t, cell, np.full(t.shape, 500.0, np.float32),
+                         200.0, 100.0, 8)
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+def test_shard_bucket_width_is_shared_by_nearby_bursts(shards):
+    """Under a mesh a shard's bucket width is its largest burst rounded
+    up to an eighth of its power of two (97 and 103 both give 104), so
+    streams of one rate share one program; on one device it is the
+    burst itself.  Every request lands in its tick and shard once."""
+    widths = []
+    for burst in (97, 103):
+        stream = _burst_stream(burst)
+        ids, now, live, n_epochs = _tick_buckets(stream, 50.0, 2, shards)
+        assert ids.shape[:2] == (6, shards) and n_epochs == 3
+        got = np.sort(ids[ids >= 0])
+        np.testing.assert_array_equal(got, np.arange(stream.n_requests))
+        assert (ids[1, 0] >= 0).sum() == burst
+        widths.append(ids.shape[-1])
+    assert widths == ([104, 104] if shards > 1 else [97, 103])
+
+
 def test_merge_shard_buffers_semantics():
     edges = jnp.asarray([1.0, 10.0, 100.0])
     buf = MetricBuffer(
@@ -286,3 +327,131 @@ def test_merge_shard_buffers_semantics():
     # intensive gauge averages over writing shards
     got = np.asarray(out.gauges["queue_depth"])
     assert got[0] == 3.0 and got[1] == 4.0 and np.isnan(got[2])
+
+
+# ------------------------------------------------ the four-chip cell's path
+# The benchmark's four-chip deployment cut to 64 cells, on four forced
+# host devices: its own entry serves it (setting the process's cells
+# mesh), the plain reference judges it under the cell's limits, and the
+# epoch program's exchange is read from its HLO, before and after XLA's
+# passes.
+MESH4_SCRIPT = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+sys.path.insert(0, sys.argv[1])
+import json
+from chipbench.lib import check
+from chipbench.lib.registry import Bench
+from repro.serve.engine import first_epoch_args, make_serve_engine
+from repro.sharding.runtime import get_mesh_info
+
+bench = Bench()
+bytes_of = bench.reader("exchange_bytes_per_tick.mesh").all_reduce_bytes
+wl = bench.workload("serve64k_mesh4")
+config = bench.config(wl["config"])
+config["fleet"]["n_cells"] = 64
+mix = dict(bench.traffic(wl["traffic"]), horizon_ms=500.0, epoch_ms=250.0)
+entry = bench.entry(config["entry"])
+cell = entry.build(config, mix, 2**31 + 5)
+rep = entry.one_pass(cell).report
+verdict = check.judge(check.compare_serve(cell.setup, rep),
+                      bench.limits("serve64k_mesh4"))
+
+out = {"mesh_cells": rep["mesh_cells"], "served": rep["served_requests"],
+       "correct": verdict["correct"], "checks": verdict["checks"],
+       "counters": rep["counters"], "spans": sorted(rep["spans"])}
+for name, mesh in (("sharded", get_mesh_info().mesh), ("single", None)):
+    engine = make_serve_engine(cell.policy, cell.cfg, mesh=mesh)
+    args = first_epoch_args(engine, cell.policy, cell.params, cell.scenario,
+                            cell.stream, cell.key)
+    lowered = engine.run_epoch.lower(*args)
+    hlo = lowered.as_text(dialect="hlo")
+    reduces = [ln for ln in hlo.splitlines() if " all-reduce(" in ln]
+    compiled = [bytes_of(ln.strip())
+                for ln in lowered.compile().as_text().splitlines()]
+    out[name] = {"all_reduces": len(reduces),
+                 "tagged": sum('stage="exchange"' in ln for ln in reduces),
+                 "exchange_tags": hlo.count('stage="exchange"'),
+                 "compiled_bytes": sorted(b for b in compiled
+                                          if b is not None)}
+print("RESULT", json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def mesh4():
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", MESH4_SCRIPT, REPO],
+                          capture_output=True, text=True, env=env,
+                          cwd=REPO, timeout=900)
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    line = next(x for x in proc.stdout.splitlines()
+                if x.startswith("RESULT "))
+    return json.loads(line[len("RESULT "):])
+
+
+def test_mesh4_cell_matches_the_reference(mesh4):
+    """The sharded ``serve_stream`` of the four-chip deployment passes the
+    plain reference's comparison within the cell's limits."""
+    assert mesh4["mesh_cells"] == 4 and mesh4["served"] > 0
+    assert mesh4["correct"] is True, mesh4["checks"]
+
+
+def test_mesh4_psums_carry_the_exchange_tag(mesh4):
+    """Every cells-axis psum of the sharded epoch program is tagged
+    ``stage="exchange"``; the single-device program has no psum and no
+    such tag."""
+    sharded, single = mesh4["sharded"], mesh4["single"]
+    assert sharded["all_reduces"] > 0
+    assert sharded["tagged"] == sharded["all_reduces"]
+    assert single["all_reduces"] == 0 and single["exchange_tags"] == 0
+
+
+def test_mesh4_exchange_bytes_count_the_tick_psums(mesh4):
+    """The compiled sharded program exchanges, a tick, four int32 arrays
+    over the fleet's 64 cells and three int32 scalars (the psums whose
+    results the tick reads), and once an epoch the 4-byte decision
+    count, as ``exchange_bytes_per_tick``'s parser reads the compiled
+    all-reduces; the compiled single-device program has none."""
+    per_tick = 4 * 64 * 4 + 3 * 4
+    assert sum(mesh4["sharded"]["compiled_bytes"]) == per_tick + 4
+    assert 4 in mesh4["sharded"]["compiled_bytes"]
+    assert mesh4["single"]["compiled_bytes"] == []
+
+
+def test_mesh4_epoch_program_traced_once(mesh4):
+    """The initial state arrives on the mesh as every epoch returns it,
+    so a build traces the sharded epoch program once."""
+    assert mesh4["counters"]["epoch_traces"] == 1
+
+
+def test_mesh4_report_records_the_merge(mesh4):
+    assert "serve.merge" in mesh4["spans"]
+
+
+def test_merge_span_is_a_child_of_the_report(monkeypatch):
+    """``serve.merge`` (the shard copies to the host, merged) is recorded
+    inside ``serve.report``, once a call."""
+    from repro.serve import engine as engine_mod
+    from repro.telemetry import profiling
+    recs = []
+    orig = profiling.recording
+
+    @contextlib.contextmanager
+    def keep():
+        with orig() as rec:
+            recs.append(rec)
+            yield rec
+    monkeypatch.setattr(engine_mod, "recording", keep)
+    pol = heuristic_greedy_policy(FleetConfig(n_max=N_MAX).spec())
+    scn, stream, scfg = _case(rounds=2)
+    serve_stream(pol, pol.init(jax.random.PRNGKey(0)), scn, stream, scfg,
+                 key=jax.random.PRNGKey(7))
+    spans = recs[-1].spans
+    merges = [s for s in spans if s[0] == "serve.merge"]
+    assert len(merges) == 1
+    parent = spans[merges[0][3]]
+    assert parent[0] == "serve.report"
+    assert parent[1] <= merges[0][1] <= merges[0][2] <= parent[2]

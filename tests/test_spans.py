@@ -40,7 +40,7 @@ REPO = Path(__file__).resolve().parents[1]
 
 SPANS = {"serve.bucket", "serve.arrays", "serve.build", "serve.epoch",
          "serve.refresh", "serve.h2d", "serve.dispatch", "serve.wait",
-         "serve.count", "serve.report"}
+         "serve.count", "serve.report", "serve.merge"}
 STAGES = ("admit", "rounds", "observe", "act", "step", "scatter",
           "telemetry", "occupancy")
 
@@ -129,11 +129,11 @@ def test_serve_stream_spans(verbose, capsys):
     assert not {"prep", "epoch"} & set(spans)   # the harness's names
     n_epochs = rep["n_epochs"]
     for name in want - {"serve.bucket", "serve.arrays", "serve.build",
-                        "serve.report", "serve.count"}:
+                        "serve.report", "serve.merge", "serve.count"}:
         assert spans[name]["n"] == n_epochs, name
     assert spans["serve.count"]["n"] == n_epochs - 1
     for name in ("serve.bucket", "serve.arrays", "serve.build",
-                 "serve.report"):
+                 "serve.report", "serve.merge"):
         assert spans[name]["n"] == 1, name
     assert all(v["self_s"] >= 0 for v in spans.values())
     assert sum(v["self_s"] for v in spans.values()) <= wall
