@@ -531,9 +531,13 @@ def _tick_buckets(stream: RequestStream, tick_ms: float,
     ``[s·C/S, (s+1)·C/S)``, matching the mesh's block partition of the
     scenario).  Returns (T, S, A) -1-padded id rows (A = the max
     per-tick-per-shard burst, under a mesh rounded up to 1/8 of its power
-    of two; within a row ids stay in arrival order, so
-    per-cell FIFO admission order is shard-invariant), the (T,) tick
-    times, the (T,) live-tick mask, and the epoch count.
+    of two), the (T,) tick times, the (T,) live-tick mask, and the epoch
+    count.  The rows are filled by one stable sort of the admitted
+    requests on ``tick·S + shard``, each request going to its rank within
+    its bucket.  The sort keeps ascending request id inside a bucket, so
+    a row lists its ids in stream order (arrival order for a stream
+    sorted by time, as ``RequestStream`` documents; the sort does not
+    rely on it) and per-cell FIFO admission order is shard-invariant.
 
     The serving window is a function of the horizon alone: the
     ``n_ticks = ceil(horizon/tick) + 1`` live ticks cover every arrival
@@ -551,8 +555,8 @@ def _tick_buckets(stream: RequestStream, tick_ms: float,
     ok = tick_of < n_ticks
     shard_of = (np.asarray(stream.cell, np.int64)
                 // (stream.n_cells // n_shards))
-    counts = np.bincount((tick_of * n_shards + shard_of)[ok],
-                         minlength=T * n_shards)
+    key = (tick_of * n_shards + shard_of)[ok]
+    counts = np.bincount(key, minlength=T * n_shards)
     A = max(1, int(counts.max()) if counts.size else 1)
     if n_shards > 1:
         # a shard's largest burst varies from stream to stream even where
@@ -560,12 +564,15 @@ def _tick_buckets(stream: RequestStream, tick_ms: float,
         # two, so streams of one rate share one compiled program
         q = 1 << max(0, A.bit_length() - 4)
         A = -(-A // q) * q
-    ids = np.full((T, n_shards, A), -1, np.int32)
-    cursor = np.zeros((T, n_shards), np.int64)
-    for i in np.nonzero(ok)[0]:
-        t, s = tick_of[i], shard_of[i]
-        ids[t, s, cursor[t, s]] = i
-        cursor[t, s] += 1
+    # keys held in the narrowest unsigned type: at 16 bits or fewer
+    # NumPy's stable sort is a radix sort
+    order = np.argsort(key.astype(np.min_scalar_type(T * n_shards)),
+                       kind="stable")
+    row = key[order]
+    rank = np.arange(row.size) - (np.cumsum(counts) - counts)[row]
+    ids = np.full((T * n_shards, A), -1, np.int32)
+    ids[row, rank] = np.flatnonzero(ok)[order]
+    ids = ids.reshape(T, n_shards, A)
     now = (np.arange(T, dtype=np.float64) * tick_ms).astype(np.float32)
     live = np.arange(T) < n_ticks
     return ids, now, live, n_epochs
